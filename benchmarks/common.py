@@ -9,8 +9,12 @@ from repro.backend import make_backend
 from repro.core.engine import SimChipArray
 from repro.flash.params import DEFAULT_PARAMS
 from repro.frontend import RunConfig, RunReport, replay
+from repro.kernels import enable_compile_cache
 from repro.workload.runner import run
 from repro.workload.ycsb import generate
+
+# Every benchmark imports this module before its first compile.
+enable_compile_cache()
 
 # Paper grids (§VI-A4/A5, §VII)
 COVERAGES = (0.0, 0.10, 0.25, 0.50, 0.75)

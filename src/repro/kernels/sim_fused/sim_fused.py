@@ -33,7 +33,10 @@ from jax.experimental import pallas as pl
 
 from repro.core.bits import mix2_32
 from repro.core.randomize import _HI_SALT, _LO_SALT
+from repro.kernels.lanes import (exclusive_prefix_count, f32_to_u32,
+                                 pack_bits, u32_to_f32)
 
+HIGHEST = jax.lax.Precision.HIGHEST   # f32 operands up to 2**16 - 1
 SLOTS = 512
 CHUNKS = 64
 WORDS = 16
@@ -56,36 +59,45 @@ def _match_bits(lo, hi, q_lo, q_hi, m_lo, m_hi, page, seed, *,
         q_lo = q_lo ^ mix2_32(ctr, _LO_SALT, jnp)
         q_hi = q_hi ^ mix2_32(ctr, _HI_SALT, jnp)
     mismatch = ((lo ^ q_lo) & m_lo) | ((hi ^ q_hi) & m_hi)
-    return (mismatch == 0).astype(jnp.uint32)
+    return mismatch == 0
 
 
-def _pack_bits(bits, lead_shape):
-    """(..., 512) {0,1} -> (..., 16) uint32 packed bitmap, in VMEM."""
-    b = bits.reshape(*lead_shape, BITMAP_WORDS, 32)
-    sh = jax.lax.broadcasted_iota(jnp.uint32, b.shape, b.ndim - 1)
-    return (b << sh).sum(axis=-1).astype(jnp.uint32)
+def _onehot(shape, pred):
+    """f32 0/1 matrix of ``shape``, one where ``pred(row, col)`` holds."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return pred(row, col).astype(jnp.float32)
 
 
-def _split16_select(sel_f32, lo, hi, page_block: int):
-    """One-hot chunk selection via the split-16 exact MXU matmul.
+def _select_chunk(lane_on, lo, hi):
+    """Compact one chunk's 8 slots out of the planes, on the MXU.
 
-    sel_f32: (PB, M, 64) or (PB, 64) one-hot rows; lo/hi: (PB, 512) planes.
-    Returns the selected chunk words, uint32, front-packed along M.
+    lane_on: (R, 512) bool, set on the 8 lanes of the selected chunk (or
+    on none); lo/hi: (R, 512) uint32 planes.  Returns (R, 16) uint32 chunk
+    words with lo/hi interleaved per slot, zeros where nothing was
+    selected.  Each output sums a single selected word, split into 16-bit
+    halves so f32 holds it exactly.
     """
-    lo_c = lo.reshape(page_block, CHUNKS, SLOTS_PER_CHUNK)
-    hi_c = hi.reshape(page_block, CHUNKS, SLOTS_PER_CHUNK)
-    chunks = jnp.stack([lo_c, hi_c], axis=-1).reshape(
-        page_block, CHUNKS, WORDS)                 # interleaved words
-    c_lo = (chunks & jnp.uint32(0xFFFF)).astype(jnp.float32)
-    c_hi = (chunks >> jnp.uint32(16)).astype(jnp.float32)
-    contract = sel_f32.ndim - 1
-    dn = (((contract,), (1,)), ((0,), (0,)))
-    g_lo = jax.lax.dot_general(sel_f32, c_lo, dn,
-                               preferred_element_type=jnp.float32)
-    g_hi = jax.lax.dot_general(sel_f32, c_hi, dn,
-                               preferred_element_type=jnp.float32)
-    return g_lo.astype(jnp.uint32) | (g_hi.astype(jnp.uint32)
-                                      << jnp.uint32(16))
+    to_even = _onehot((SLOTS, WORDS), lambda s, w: w == 2 * (s % 8))
+    to_odd = _onehot((SLOTS, WORDS), lambda s, w: w == 2 * (s % 8) + 1)
+
+    def pick(plane, spread):
+        p = jnp.where(lane_on, plane, jnp.uint32(0))
+        w_lo = jnp.dot(u32_to_f32(p & jnp.uint32(0xFFFF)), spread,
+                       precision=HIGHEST, preferred_element_type=jnp.float32)
+        w_hi = jnp.dot(u32_to_f32(p >> jnp.uint32(16)), spread,
+                       precision=HIGHEST, preferred_element_type=jnp.float32)
+        return f32_to_u32(w_lo) | (f32_to_u32(w_hi) << jnp.uint32(16))
+    return pick(lo, to_even) | pick(hi, to_odd)
+
+
+def _chunk_lanes(chunk_sel):
+    """(R, 64) bool chunk selection -> (R, 512) bool lane selection."""
+    spread = _onehot((CHUNKS, SLOTS),
+                     lambda c, s: s // SLOTS_PER_CHUNK == c)
+    return jnp.dot(chunk_sel.astype(jnp.bfloat16),
+                   spread.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32) > 0
 
 
 # ---------------------------------------------------------------------------
@@ -104,18 +116,24 @@ def _fused_kernel(lo_ref, hi_ref, q_ref, m_ref, page_ref, seed_ref, bm_ref,
                        shape=(page_block, SLOTS), randomized=randomized)
 
     # --- search output: packed 64 B bitmap per page
-    bm_ref[...] = _pack_bits(bits, (page_block,))[None]
+    bm_ref[...] = pack_bits(bits)[None]
 
     # --- gather phase, reusing the resident planes
-    chunk_bits = (bits.reshape(page_block, CHUNKS, SLOTS_PER_CHUNK
-                               ).sum(axis=2) > 0).astype(jnp.uint32)
-    pos = jnp.cumsum(chunk_bits, axis=1, dtype=jnp.uint32) - chunk_bits
-    m_ids = jax.lax.broadcasted_iota(jnp.uint32,
+    gather = _onehot((SLOTS, CHUNKS), lambda s, c: s // SLOTS_PER_CHUNK == c)
+    chunk_bits = jnp.dot(bits.astype(jnp.bfloat16),
+                         gather.astype(jnp.bfloat16),
+                         preferred_element_type=jnp.float32) > 0
+    pos = exclusive_prefix_count(chunk_bits)           # (PB, 64) int32
+    m_ids = jax.lax.broadcasted_iota(jnp.int32,
                                      (page_block, max_out, CHUNKS), 1)
-    sel = ((pos[:, None, :] == m_ids) & (chunk_bits[:, None, :] == 1)
-           ).astype(jnp.float32)
-    out_ref[...] = _split16_select(sel, lo, hi, page_block)[None]
-    cnt_ref[...] = chunk_bits.sum(axis=1, dtype=jnp.int32)[None]
+    sel = (pos[:, None, :] == m_ids) & chunk_bits[:, None, :]
+    rows = page_block * max_out
+    lanes = _chunk_lanes(sel.reshape(rows, CHUNKS))
+    planes = [jnp.broadcast_to(p[:, None, :], (page_block, max_out, SLOTS)
+                               ).reshape(rows, SLOTS) for p in (lo, hi)]
+    out_ref[...] = _select_chunk(lanes, *planes).reshape(
+        1, page_block, max_out, WORDS)
+    cnt_ref[...] = chunk_bits.astype(jnp.int32).sum(axis=1)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("page_block", "max_out",
@@ -184,24 +202,20 @@ def _lookup_kernel(klo_ref, khi_ref, vlo_ref, vhi_ref, q_ref, m_ref,
                        shape=(row_block, SLOTS), randomized=randomized)
 
     # Raw packed bitmap (bit-identical to a search command's bus payload).
-    bm_ref[...] = _pack_bits(bits, (row_block,))
+    bm_ref[...] = pack_bits(bits)
 
     # First matching *user* slot: the header chunk (slots 0..7) never holds
     # entries — index software strips it host-side; here the strip happens
     # in-VMEM so the whole match->gather hop needs no host round trip.
-    slot = jax.lax.broadcasted_iota(jnp.uint32, (row_block, SLOTS), 1)
-    user = jnp.where(slot >= jnp.uint32(SLOTS_PER_CHUNK), bits,
-                     jnp.uint32(0))
-    first = jnp.where(user == 1, slot, jnp.uint32(NO_SLOT)).min(axis=1)
-    found = first < NO_SLOT                            # (RB,)
-    slot_ref[...] = first.astype(jnp.int32)[:, None]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (row_block, SLOTS), 1)
+    user = bits & (slot >= SLOTS_PER_CHUNK)
+    first = jnp.where(user, slot, NO_SLOT).min(axis=1, keepdims=True)
+    slot_ref[...] = first                              # (RB, 1) int32
 
-    # Gather the matched slot's chunk from the paired VALUE page row.
-    chunk = jnp.minimum(first >> jnp.uint32(3), jnp.uint32(CHUNKS - 1))
-    cidx = jax.lax.broadcasted_iota(jnp.uint32, (row_block, CHUNKS), 1)
-    sel = ((cidx == chunk[:, None]) & found[:, None]).astype(jnp.float32)
-    val_ref[...] = _split16_select(sel, vlo_ref[...], vhi_ref[...],
-                                   row_block)
+    # Gather the matched slot's chunk from the paired VALUE page row; a
+    # row with no match (first == NO_SLOT) selects no lane.
+    lanes = slot // SLOTS_PER_CHUNK == first // SLOTS_PER_CHUNK
+    val_ref[...] = _select_chunk(lanes, vlo_ref[...], vhi_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("row_block", "randomized",

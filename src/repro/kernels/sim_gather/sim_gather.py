@@ -24,8 +24,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.lanes import exclusive_prefix_count, f32_to_u32, u32_to_f32
+
 CHUNKS = 64
 WORDS = 16
+HIGHEST = jax.lax.Precision.HIGHEST   # f32 operands up to 2**16 - 1
 
 
 def _gather_kernel(chunk_ref, bm_ref, out_ref, cnt_ref, *, page_block: int,
@@ -35,24 +38,24 @@ def _gather_kernel(chunk_ref, bm_ref, out_ref, cnt_ref, *, page_block: int,
 
     j = jax.lax.broadcasted_iota(jnp.uint32, (page_block, CHUNKS), 1)
     word = jnp.where(j < 32, bm[:, 0:1], bm[:, 1:2])  # (PB, 64)
-    bit = (word >> (j % 32)) & jnp.uint32(1)
-    pos = jnp.cumsum(bit, axis=1, dtype=jnp.uint32) - bit
+    bit = ((word >> (j % 32)) & jnp.uint32(1)) == 1
+    pos = exclusive_prefix_count(bit)                 # (PB, 64) int32
 
-    m_ids = jax.lax.broadcasted_iota(jnp.uint32, (page_block, max_out, CHUNKS), 1)
-    sel = ((pos[:, None, :] == m_ids) & (bit[:, None, :] == 1)
+    m_ids = jax.lax.broadcasted_iota(jnp.int32,
+                                     (page_block, max_out, CHUNKS), 1)
+    sel = ((pos[:, None, :] == m_ids) & bit[:, None, :]
            ).astype(jnp.float32)                      # (PB, M, 64)
 
     # Split-16 exact integer matmul on the MXU.
-    c_lo = (chunks & jnp.uint32(0xFFFF)).astype(jnp.float32)
-    c_hi = (chunks >> jnp.uint32(16)).astype(jnp.float32)
+    c_lo = u32_to_f32(chunks & jnp.uint32(0xFFFF))
+    c_hi = u32_to_f32(chunks >> jnp.uint32(16))
     dn = (((2,), (1,)), ((0,), (0,)))                 # batch PB, contract 64
-    out_lo = jax.lax.dot_general(sel, c_lo, dn,
+    out_lo = jax.lax.dot_general(sel, c_lo, dn, precision=HIGHEST,
                                  preferred_element_type=jnp.float32)
-    out_hi = jax.lax.dot_general(sel, c_hi, dn,
+    out_hi = jax.lax.dot_general(sel, c_hi, dn, precision=HIGHEST,
                                  preferred_element_type=jnp.float32)
-    out_ref[...] = (out_lo.astype(jnp.uint32)
-                    | (out_hi.astype(jnp.uint32) << jnp.uint32(16)))
-    cnt_ref[...] = bit.sum(axis=1, dtype=jnp.int32)[:, None]
+    out_ref[...] = f32_to_u32(out_lo) | (f32_to_u32(out_hi) << jnp.uint32(16))
+    cnt_ref[...] = bit.astype(jnp.int32).sum(axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit,

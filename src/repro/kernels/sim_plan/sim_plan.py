@@ -21,7 +21,8 @@ flash address and device seed on the sublane axis, so the §IV-C1
 randomization stream regenerates in-kernel and one launch batches pages
 from different chips.  Plans ride a *group* axis: the grid is
 (page tiles, plan groups), each group owning (P, 2) query/mask rows plus a
-(P,) flags row marking every pass include / exclude / padding.
+(P, 1) flags column marking every pass include / exclude / padding (a
+(1, P) block of the (G, P) flags would break the TPU's (8, 128) tiling).
 
 VMEM per step ~= 2 * PB * 2 KiB (planes) + P * PB * 2 KiB (pass-match
 intermediate); the default PB=8 keeps a 128-pass plan at ~2 MiB.
@@ -36,6 +37,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.bits import mix2_32
 from repro.core.randomize import _HI_SALT, _LO_SALT
+from repro.kernels.lanes import pack_bits
 
 SLOTS = 512
 BITMAP_WORDS = 16
@@ -52,12 +54,12 @@ def _plan_kernel(lo_ref, hi_ref, q_ref, m_ref, f_ref, page_ref, seed_ref,
     hi = hi_ref[...]
     q = q_ref[...][0]                      # (P, 2): this group's pass rows
     m = m_ref[...][0]
-    f = f_ref[...][0]                      # (P,) uint32 pass flags
+    f = f_ref[...][0]                      # (P, 1) uint32 pass flags
 
-    q_lo = q[:, 0][:, None, None]          # (P, 1, 1)
-    q_hi = q[:, 1][:, None, None]
-    m_lo = m[:, 0][:, None, None]
-    m_hi = m[:, 1][:, None, None]
+    q_lo = q[:, 0:1][:, :, None]           # (P, 1, 1)
+    q_hi = q[:, 1:2][:, :, None]
+    m_lo = m[:, 0:1][:, :, None]
+    m_hi = m[:, 1:2][:, :, None]
     if randomized:
         # Deserializer: regenerate the slot-address-counter stream in VMEM
         # from each staged page's own flash address and device seed.
@@ -70,22 +72,19 @@ def _plan_kernel(lo_ref, hi_ref, q_ref, m_ref, f_ref, page_ref, seed_ref,
         q_hi = q_hi ^ mix2_32(ctr, _HI_SALT, jnp)[None]
 
     mismatch = ((lo[None] ^ q_lo) & m_lo) | ((hi[None] ^ q_hi) & m_hi)
-    bits = (mismatch == 0).astype(jnp.uint32)      # (P, PB, 512)
+    bits = (mismatch == 0).astype(jnp.int32)       # (P, PB, 512) 0/1
 
     # In-latch accumulation (Fig 10): masked OR over the include passes,
     # masked OR over the exclude passes, one AND-NOT combine — all while
     # the per-pass bits are still resident in VMEM.
-    is_inc = (f == jnp.uint32(PASS_INCLUDE)).astype(jnp.uint32)[:, None, None]
-    is_exc = (f == jnp.uint32(PASS_EXCLUDE)).astype(jnp.uint32)[:, None, None]
+    is_inc = (f == jnp.uint32(PASS_INCLUDE)).astype(jnp.int32)[:, :, None]
+    is_exc = (f == jnp.uint32(PASS_EXCLUDE)).astype(jnp.int32)[:, :, None]
     inc = (bits & is_inc).max(axis=0)              # (PB, 512) 0/1
     exc = (bits & is_exc).max(axis=0)
-    acc = inc & ~exc          # bits are 0/1: ~0 keeps inc, ~1 clears it
+    acc = (inc & (1 - exc)) == 1
 
     # Only the combined bitmap leaves VMEM: 512 bits -> 16 uint32 (64 B).
-    b = acc.reshape(page_block, BITMAP_WORDS, 32)
-    sh = jax.lax.broadcasted_iota(
-        jnp.uint32, (page_block, BITMAP_WORDS, 32), 2)
-    out_ref[...] = ((b << sh).sum(axis=2).astype(jnp.uint32))[None]
+    out_ref[...] = pack_bits(acc)[None]
 
 
 @functools.partial(
@@ -108,7 +107,7 @@ def _sim_plan_call(lo, hi, queries, masks, flags, page_ids, page_seeds, *,
             pl.BlockSpec((page_block, SLOTS), lambda i, j: (i, 0)),
             pl.BlockSpec((1, n_passes, 2), lambda i, j: (j, 0, 0)),
             pl.BlockSpec((1, n_passes, 2), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((1, n_passes), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, n_passes, 1), lambda i, j: (j, 0, 0)),
             pl.BlockSpec((page_block, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((page_block, 1), lambda i, j: (i, 0)),
         ],
@@ -119,7 +118,7 @@ def _sim_plan_call(lo, hi, queries, masks, flags, page_ids, page_seeds, *,
         interpret=interpret,
     )(jnp.asarray(lo, jnp.uint32), jnp.asarray(hi, jnp.uint32),
       jnp.asarray(queries, jnp.uint32), jnp.asarray(masks, jnp.uint32),
-      jnp.asarray(flags, jnp.uint32),
+      jnp.asarray(flags, jnp.uint32)[..., None],
       jnp.asarray(page_ids, jnp.uint32).reshape(-1, 1),
       jnp.asarray(page_seeds, jnp.uint32).reshape(-1, 1))
 

@@ -39,6 +39,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.bits import mix2_32
 from repro.core.randomize import _HI_SALT, _LO_SALT
+from repro.kernels.lanes import pack_bits
 
 SLOTS = 512
 BITMAP_WORDS = 16
@@ -69,13 +70,11 @@ def _search_kernel(lo_ref, hi_ref, q_ref, m_ref, page_ref, seed_ref, out_ref,
         q_hi = q_hi ^ s_hi[None]
 
     mismatch = ((lo[None] ^ q_lo) & m_lo) | ((hi[None] ^ q_hi) & m_hi)
-    bits = (mismatch == 0).astype(jnp.uint32)      # (Q, PB, 512)
+    bits = mismatch == 0                           # (Q, PB, 512) bool
 
     # In-VMEM bitmap packing: 512 bits -> 16 uint32 (the 64 B bus payload).
-    b = bits.reshape(n_queries, page_block, BITMAP_WORDS, 32)
-    sh = jax.lax.broadcasted_iota(
-        jnp.uint32, (n_queries, page_block, BITMAP_WORDS, 32), 3)
-    out_ref[...] = (b << sh).sum(axis=3).astype(jnp.uint32)
+    packed = pack_bits(bits.reshape(n_queries * page_block, SLOTS))
+    out_ref[...] = packed.reshape(n_queries, page_block, BITMAP_WORDS)
 
 
 @functools.partial(

@@ -6,32 +6,17 @@ Multi-pod:  (pod=2, data=16, model=16) = 512 chips.
 A function (not a module-level constant) so importing this module never
 touches jax device state — the dry-run must set XLA_FLAGS before any jax
 initialization.
-
-jax-version constraint: ``jax.sharding.AxisType`` (and the ``axis_types``
-parameter of ``jax.make_mesh``) only exist from jax 0.5; on the pinned
-jax 0.4.37 every mesh axis is implicitly Auto, which is exactly what we
-ask for on newer jax — so ``make_mesh`` below is semantically identical
-on both sides of the version split.
 """
 from __future__ import annotations
 
 import jax
-
-
-def _auto_axis_types(n_axes: int):
-    """(AxisType.Auto,) * n on jax >= 0.5, None on older jax."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n_axes
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where supported."""
-    types = _auto_axis_types(len(axes))
-    if types is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=types)
+    """jax.make_mesh with every axis of type Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def production_mesh_spec(*, multi_pod: bool = False):

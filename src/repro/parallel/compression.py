@@ -88,69 +88,19 @@ def make_compressed_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
         aux = jax.lax.pmean(aux, "pod")
         return g_new, e_new, loss, aux
 
-    def per_pod_stacked(params, err_state, tokens, labels, fe):
-        """jax 0.4.x fallback: the same per-pod compressed reduction as an
-        explicit vmap over a leading pod axis.
-
-        Partial-manual shard_map (manual ``pod``, auto data/model) trips an
-        XLA CHECK (``sharding.IsManualSubgroup()``) in the pinned
-        jaxlib 0.4.36, so on old jax we compute each pod's gradient with
-        vmap (params broadcast — the replicated-over-pod contract), run the
-        identical int8 error-feedback math on the stacked leaves, and take
-        the dequantized mean — the same psum semantics, just expressed
-        without a named pod axis.  XLA still shards the stacked batch over
-        the mesh from the operand shardings.
-        """
-        n_pods = jax.tree.leaves(err_state)[0].shape[0]
-        tok = tokens.reshape(n_pods, -1, *tokens.shape[1:])
-        lab = labels.reshape(n_pods, -1, *labels.shape[1:])
-        fe_p = fe.reshape(n_pods, -1, *fe.shape[1:]) if fe is not None \
-            else None
-
-        def one_pod(tokens, labels, fe):
-            grad_fn = jax.value_and_grad(lm_loss, has_aux=True)
-            (_, (loss, aux)), grads = grad_fn(params, cfg, tokens, labels,
-                                              fe, block_specs, act_spec)
-            return grads, loss, aux
-
-        grads_stack, loss, aux = jax.vmap(
-            one_pod, in_axes=(0, 0, 0 if fe_p is not None else None)
-        )(tok, lab, fe_p)
-
-        def compress(g_stack, err_stack):
-            target = g_stack + err_stack              # (n_pods, ...)
-            reduce_axes = tuple(range(1, target.ndim))
-            scale = jnp.maximum(
-                jnp.max(jnp.abs(target), axis=reduce_axes, keepdims=True)
-                / 127.0, 1e-12)
-            q = quantize_int8(target, scale)
-            deq = q.astype(jnp.float32) * scale
-            return deq.mean(axis=0), target - deq
-
-        flat = jax.tree.map(compress, grads_stack, err_state)
-        grads = jax.tree.map(lambda t: t[0], flat,
-                             is_leaf=lambda x: isinstance(x, tuple))
-        err_state = jax.tree.map(lambda t: t[1], flat,
-                                 is_leaf=lambda x: isinstance(x, tuple))
-        return grads, err_state, loss.mean(), aux.mean()
-
     def train_step(params, opt_state, err_state, batch):
         tokens, labels = batch["tokens"], batch["labels"]
         fe = batch.get("frontend")
-        if hasattr(jax, "shard_map"):
-            sm = jax.shard_map(
-                per_pod, mesh=mesh, axis_names={"pod"},
-                in_specs=(P(), jax.tree.map(lambda _: P("pod"), err_state),
-                          P("pod"), P("pod"),
-                          P("pod") if fe is not None else P()),
-                out_specs=(P(), jax.tree.map(lambda _: P("pod"), err_state),
-                           P(), P()),
-                check_vma=False)
-            grads, err_state, loss, aux = sm(params, err_state, tokens,
-                                             labels, fe)
-        else:
-            grads, err_state, loss, aux = per_pod_stacked(
-                params, err_state, tokens, labels, fe)
+        sm = jax.shard_map(
+            per_pod, mesh=mesh, axis_names={"pod"},
+            in_specs=(P(), jax.tree.map(lambda _: P("pod"), err_state),
+                      P("pod"), P("pod"),
+                      P("pod") if fe is not None else P()),
+            out_specs=(P(), jax.tree.map(lambda _: P("pod"), err_state),
+                       P(), P()),
+            check_vma=False)
+        grads, err_state, loss, aux = sm(params, err_state, tokens,
+                                         labels, fe)
         params, opt_state, opt_metrics = adamw_update(grads, opt_state,
                                                       params, opt_cfg)
         metrics = {"loss": loss, "aux_loss": aux, **opt_metrics}

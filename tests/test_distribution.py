@@ -126,12 +126,9 @@ def test_pod_compressed_allreduce_converges():
 
 
 def test_multi_pod_mesh_shapes():
-    # Note: the pre-fix AssertionError here was this test's
-    # ``assert proc.returncode == 0`` surfacing the subprocess
-    # AttributeError on jax.sharding.AxisType (absent in jax 0.4.x);
-    # the mesh-shape computation itself is correct — verified below via
-    # production_mesh_spec (256 / 512 chips) plus an 8-device (2,2,2)
-    # analogue built through the same make_mesh compat path.
+    # The production mesh shapes come from production_mesh_spec (256 /
+    # 512 chips); an 8-device (2,2,2) analogue is built through the same
+    # make_mesh.
     out = run_devices(textwrap.dedent("""
         import json, jax
         from repro.launch.mesh import make_mesh, production_mesh_spec
