@@ -1,0 +1,1 @@
+"""The SiM benchmark: one cell per run, driven by ``BENCHMARK.json``."""
