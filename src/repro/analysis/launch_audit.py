@@ -25,8 +25,10 @@ program-group — checking after each phase:
   * SIM104 — ``staged_bytes`` deltas equal PAGE_BYTES x newly-staged
     pages (and ZERO when warm), ``result_bytes`` deltas equal the exact
     64 B-granular payload the command mix implies, plane operands in the
-    jaxpr are exactly padded_rows(unique pages) x PAGE_BYTES, and
-    ``kernel_launches`` equals the recorded launch count;
+    jaxpr are exactly padded_rows(unique pages) x PAGE_BYTES,
+    ``launched_rows`` deltas equal the page rows of the recorded launches'
+    plane operands, and ``kernel_launches`` equals the recorded launch
+    count;
   * SIM105 — the unoptimized-HLO cross-check: parameter/ROOT bytes parsed
     from ``lower().compiler_ir('hlo')`` text (via launch/hlo_analysis)
     match the jaxpr operand/result bytes.
@@ -128,6 +130,20 @@ def summarize_jaxpr(closed) -> JaxprSummary:
         out_shapes=tuple(_aval_shape(v) for v in outvars),
         in_bytes=sum(_aval_bytes(v) for v in invars),
         out_bytes=sum(_aval_bytes(v) for v in outvars))
+
+
+def _plane_operands(summary: JaxprSummary) -> list[tuple]:
+    """(shape, dtype) of a launch's page-plane operands: (..., 512)
+    uint32, one lo and one hi plane per page row."""
+    return [s for s in summary.in_shapes
+            if s[0] and s[0][-1] == 512 and s[1] == "uint32"]
+
+
+def _page_rows(summary: JaxprSummary) -> int:
+    """Page rows a launch hands its kernel: its plane operands' rows over
+    the two planes (lo, hi) of each row."""
+    return sum(math.prod(dims[:-1])
+               for dims, _ in _plane_operands(summary)) // 2
 
 
 # ----------------------------------------------------------------- recorder
@@ -249,7 +265,7 @@ class _Auditor:
         r0 = len(records)
         stats = self.backend.stats
         staged0, result0 = stats.staged_bytes, stats.result_bytes
-        launches0 = stats.kernel_launches
+        launches0, rows0 = stats.kernel_launches, stats.launched_rows
         tickets = submit()
         self.backend.flush()
         recs = records[r0:]
@@ -280,6 +296,12 @@ class _Auditor:
             phase, "staged-bytes",
             f"staged_bytes moved {stats.staged_bytes - staged0}, expected "
             f"{expect_staged_bytes} (PAGE_BYTES x newly staged pages)")
+        launched = sum(_page_rows(rec.summary) for rec in recs)
+        self.check(
+            stats.launched_rows - rows0 == launched, "SIM104", phase,
+            "counter:launched_rows",
+            f"launched_rows counted {stats.launched_rows - rows0}, the "
+            f"recorded launches' plane operands hold {launched} page rows")
         self.check(
             stats.kernel_launches - launches0 == expect_launches, "SIM104",
             phase, "counter:kernel_launches",
@@ -306,8 +328,7 @@ class _Auditor:
                              expect_pages: int) -> None:
         """The (padded) page-plane operands must be exactly
         padded_rows(unique pages) rows — PAGE_BYTES per padded row."""
-        planes = [s for s in rec.summary.in_shapes
-                  if s[0] and s[0][-1] == 512 and s[1] == "uint32"]
+        planes = _plane_operands(rec.summary)
         self.check(len(planes) >= 2, "SIM104", phase,
                    f"plane-operands:{rec.entry}",
                    f"{rec.entry} jaxpr has {len(planes)} plane-shaped "

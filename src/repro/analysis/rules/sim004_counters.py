@@ -31,8 +31,8 @@ _ALLOWED_PREFIXES = ("_flush", "submit_", "resolve_", "_resolve",
 # outside the repo): the field list as of this rule's writing.
 _FALLBACK_FIELDS = {
     "searches", "gathers", "lookups", "plans", "flushes", "kernel_launches",
-    "staged_pages", "staged_queries", "staged_bytes", "batched_searches",
-    "programs", "programs_coalesced", "result_bytes",
+    "launched_rows", "staged_pages", "staged_queries", "staged_bytes",
+    "batched_searches", "programs", "programs_coalesced", "result_bytes",
 }
 
 

@@ -124,6 +124,7 @@ import numpy as np
 from repro.core.commands import (Command, GatherResponse, LookupResponse,
                                  ReadFullResponse, SearchResponse)
 from repro.core.engine import SimChipArray
+from repro.trace import TAIL, span
 
 
 @dataclasses.dataclass
@@ -136,6 +137,9 @@ class BackendStats:
     kernel_launches: int = 0   # device launches (batched backend only)
     staged_pages: int = 0      # page rows referenced across launches
     staged_queries: int = 0    # query rows staged across launches
+    launched_rows: int = 0     # page-plane rows handed to search, plan and
+                               # lookup launches, padding and duplicates
+                               # included (gathers take chunk words)
     staged_bytes: int = 0      # page-plane bytes shipped host->device; with
                                # the device-resident store this stops growing
                                # once the working set is warm (only new or
@@ -164,14 +168,17 @@ class LazyResultBatch:
     resolution) for the whole burst at once.  Until then JAX's async
     dispatch lets host staging of burst k+1 overlap device compute of
     burst k.  ``run()`` is idempotent — later tickets find themselves
-    already resolved.
+    already resolved.  The tail runs inside a ``sim.tail`` span that
+    carries ``meta`` (the launch's ``kind``, and the ``flush`` that
+    launched it where the backend numbers its flushes).
     """
 
-    __slots__ = ("_fn", "_exc")
+    __slots__ = ("_fn", "_exc", "_meta")
 
-    def __init__(self, fn):
+    def __init__(self, fn, **meta):
         self._fn = fn
         self._exc = None
+        self._meta = meta
 
     def run(self) -> None:
         if self._exc is not None:
@@ -182,7 +189,8 @@ class LazyResultBatch:
         fn, self._fn = self._fn, None
         if fn is not None:
             try:
-                fn()
+                with span(TAIL, **self._meta):
+                    fn()
             except BaseException as e:
                 self._exc = e
                 raise
@@ -350,10 +358,11 @@ class MatchBackend(abc.ABC):
     def plan(self, cmd: Command) -> SearchResponse:
         return self.submit_plan(cmd).result()
 
-    def _defer_all(self, tickets, tail) -> None:
+    def _defer_all(self, tickets, tail, **meta) -> None:
         """Attach one lazy host tail to a burst's (cmd, ticket) pairs: the
-        launch outputs stay device-resident until the first result()."""
-        batch = LazyResultBatch(tail)
+        launch outputs stay device-resident until the first result().
+        ``meta`` labels the tail's span."""
+        batch = LazyResultBatch(tail, **meta)
         for _, t in tickets:
             t._defer(batch)
 

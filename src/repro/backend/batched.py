@@ -450,6 +450,7 @@ class BatchedKernelBackend(MatchBackend):
             interpret=self.interpret)          # (Qpad, Npad, 16) on device
 
         self.stats.kernel_launches += 1
+        self.stats.launched_rows += n_pages
         self.stats.staged_pages += len(addrs)
         self.stats.staged_queries += n_queries
         self.stats.searches += len(searches)
@@ -510,6 +511,7 @@ class BatchedKernelBackend(MatchBackend):
             interpret=self.interpret)          # (Gpad, Npad, 16) on device
 
         self.stats.kernel_launches += 1
+        self.stats.launched_rows += n_pages
         self.stats.staged_pages += len(addrs)
         self.stats.staged_queries += sum(len(i) + len(e)
                                          for i, e in groups)
@@ -544,6 +546,7 @@ class BatchedKernelBackend(MatchBackend):
             use_kernel=self.use_kernel, interpret=self.interpret)
 
         self.stats.kernel_launches += 1
+        self.stats.launched_rows += 2 * n_pad
         self.stats.lookups += n
         self.stats.staged_pages += len(set(key_addrs) | set(val_addrs))
         self.stats.staged_queries += n

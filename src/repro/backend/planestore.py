@@ -35,6 +35,7 @@ import numpy as np
 from repro.core.bits import PAGE_BYTES, SLOTS_PER_PAGE
 from repro.core.engine import SimChipArray
 from repro.kernels.layout import pages_to_planes
+from repro.trace import STAGE, span
 
 
 def next_pow2(n: int) -> int:
@@ -169,20 +170,22 @@ class PlaneStore:
     def _stage(self, addrs: list[int]) -> None:
         """Ship the listed pages' planes host->device (the only page bytes
         that ever cross after warm-up: new rows and dirty rows)."""
-        idx = jnp.asarray(np.array([self._row[a] for a in addrs], np.int32))
-        raws, ids, seeds = [], [], []
-        for a in addrs:
-            chip, local = self.chips.route(a)
-            raws.append(chip.pages[local].raw)
-            ids.append(local)
-            seeds.append(chip.device_seed & 0xFFFFFFFF)
-        lo, hi = pages_to_planes(np.stack(raws))
-        self._lo = self._lo.at[idx].set(jnp.asarray(lo))
-        self._hi = self._hi.at[idx].set(jnp.asarray(hi))
-        self._ids = self._ids.at[idx].set(
-            jnp.asarray(np.asarray(ids, np.uint32)[:, None]))
-        self._seeds = self._seeds.at[idx].set(
-            jnp.asarray(np.asarray(seeds, np.uint32)[:, None]))
+        with span(STAGE, rows=len(addrs)):
+            idx = jnp.asarray(np.array([self._row[a] for a in addrs],
+                                       np.int32))
+            raws, ids, seeds = [], [], []
+            for a in addrs:
+                chip, local = self.chips.route(a)
+                raws.append(chip.pages[local].raw)
+                ids.append(local)
+                seeds.append(chip.device_seed & 0xFFFFFFFF)
+            lo, hi = pages_to_planes(np.stack(raws))
+            self._lo = self._lo.at[idx].set(jnp.asarray(lo))
+            self._hi = self._hi.at[idx].set(jnp.asarray(hi))
+            self._ids = self._ids.at[idx].set(
+                jnp.asarray(np.asarray(ids, np.uint32)[:, None]))
+            self._seeds = self._seeds.at[idx].set(
+                jnp.asarray(np.asarray(seeds, np.uint32)[:, None]))
         self._dirty.difference_update(addrs)
         self.staged_rows += len(addrs)
         self.staged_bytes += len(addrs) * PAGE_BYTES

@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core.bits import (CHUNKS_PER_PAGE, SLOTS_PER_CHUNK,
                              popcount_words, unpack_bitmap)
 from repro.core.commands import Command, LookupResponse, Op, SearchResponse
@@ -82,6 +83,7 @@ from repro.kernels.sim_plan.ref import sim_plan_ref
 from repro.kernels.sim_plan.sim_plan import sim_plan_kernel
 from repro.kernels.sim_search.ref import sim_search_ref
 from repro.kernels.sim_search.sim_search import sim_search_kernel
+from repro.trace import span
 
 from .base import MatchBackend, Ticket
 from .batched import (resolve_gather_responses, resolve_lookup_responses,
@@ -204,6 +206,9 @@ class ShardedSsdBackend(MatchBackend):
                                        for _ in chips.chips]
         # DeviceFaultState (repro.reliability.device_faults) or None.
         self.faults = None
+        # flush() calls so far: the ``flush`` id on the flush's span and on
+        # the spans of the result tails it launched.
+        self.flush_seq = 0
 
     # ------------------------------------------------------------ geometry
     @classmethod
@@ -365,60 +370,72 @@ class ShardedSsdBackend(MatchBackend):
 
     # --------------------------------------------------------------- flush
     def flush(self) -> None:
+        # Each phase runs under its own span (repro.trace); the phases are
+        # siblings that together cover the flush body.
+        self.flush_seq += 1
+        with span(trace.FLUSH, flush=self.flush_seq):
+            self._flush()
+
+    def _flush(self) -> None:
         # Deferred write path first: one grouped chip-program pass, ONE
         # plane-store scatter for every programmed row, and one program-
         # group report to the timeline (programs queue async on each die's
         # program line; restaged dirty planes charge the storage-mode
         # channel bus — the client clock does not advance).
-        programs = self._execute_programs()
-        if programs:
-            self.store.stage_group(programs)
-            if self.timeline is not None:
-                staged, self.store.staged_log = self.store.staged_log, []
-                self.timeline.observe_program_group(
-                    [c for a in programs for c in self._program_chips(a)],
-                    restage_chips=[self.decompose(a)[0] for a in staged])
-            self.stats.staged_bytes = self.store.staged_bytes
+        programs = []
+        if self._program_queue:
+            with span(trace.FLUSH_PROGRAM):
+                programs = self._execute_programs()
+                self.store.stage_group(programs)
+                if self.timeline is not None:
+                    staged, self.store.staged_log = self.store.staged_log, []
+                    self.timeline.observe_program_group(
+                        [c for a in programs for c in self._program_chips(a)],
+                        restage_chips=[self.decompose(a)[0] for a in staged])
+                self.stats.staged_bytes = self.store.staged_bytes
         if not any(self._pending):
             if programs:
                 self.stats.flushes += 1
             return
         self.stats.flushes += 1
-        searches, lookups, gathers, plans = [], [], [], []
-        for queue in self._pending:
-            for kind, cmd, t in queue:
-                if self.faults is not None and self.faults.remap:
-                    cmd = self._remap_cmd(cmd)
-                {"search": searches, "lookup": lookups,
-                 "gather": gathers, "plan": plans}[kind].append((cmd, t))
-            queue.clear()
-        bursts: dict[int, ChipBurst] = {}
-        # Device-fault failover: commands whose chip is offline at the
-        # fault clock leave the kernel path here and are served host-side
-        # from a replica (or fail typed) — see _serve_degraded.
-        if self.faults is not None:
-            dead = self.faults.dead_chips()
-            if dead:
-                searches = self._failover("search", searches, dead, bursts)
-                lookups = self._failover("lookup", lookups, dead, bursts)
-                gathers = self._failover("gather", gathers, dead, bursts)
-                plans = self._failover("plan", plans, dead, bursts)
-        # Reliability open burst before staging (open-time ECC repairs
-        # restage corrected rows in this flush); retries and full-page
-        # fallback reads charge the owning die's timeline record.
-        opens = self._open_reliability(
-            {c.page_addr for c, _ in searches}
-            | {c.page_addr for c, _ in plans}
-            | {c.page_addr for c, _ in gathers}
-            | {c.page_addr for c, _ in lookups}
-            | {c.value_page for c, _ in lookups})
-        if opens and self.timeline is not None:
-            for a, po in opens.items():
-                c, _ = self.decompose(a)
-                b = self._burst(bursts, c)
-                b.retry_senses += po.result.retries_used
-                if po.verdict is OpenVerdict.FALLBACK_ECC:
-                    b.fallback_reads += 1
+        with span(trace.FLUSH_PLACE):
+            searches, lookups, gathers, plans = [], [], [], []
+            for queue in self._pending:
+                for kind, cmd, t in queue:
+                    if self.faults is not None and self.faults.remap:
+                        cmd = self._remap_cmd(cmd)
+                    {"search": searches, "lookup": lookups,
+                     "gather": gathers, "plan": plans}[kind].append((cmd, t))
+                queue.clear()
+            bursts: dict[int, ChipBurst] = {}
+            # Device-fault failover: commands whose chip is offline at the
+            # fault clock leave the kernel path here and are served
+            # host-side from a replica (or fail typed) — see
+            # _serve_degraded.
+            if self.faults is not None:
+                dead = self.faults.dead_chips()
+                if dead:
+                    searches = self._failover("search", searches, dead,
+                                              bursts)
+                    lookups = self._failover("lookup", lookups, dead, bursts)
+                    gathers = self._failover("gather", gathers, dead, bursts)
+                    plans = self._failover("plan", plans, dead, bursts)
+            # Reliability open burst before staging (open-time ECC repairs
+            # restage corrected rows in this flush); retries and full-page
+            # fallback reads charge the owning die's timeline record.
+            opens = self._open_reliability(
+                {c.page_addr for c, _ in searches}
+                | {c.page_addr for c, _ in plans}
+                | {c.page_addr for c, _ in gathers}
+                | {c.page_addr for c, _ in lookups}
+                | {c.value_page for c, _ in lookups})
+            if opens and self.timeline is not None:
+                for a, po in opens.items():
+                    c, _ = self.decompose(a)
+                    b = self._burst(bursts, c)
+                    b.retry_senses += po.result.retries_used
+                    if po.verdict is OpenVerdict.FALLBACK_ECC:
+                        b.fallback_reads += 1
         if searches:
             self._flush_searches(searches, bursts, opens)
         if plans:
@@ -427,14 +444,15 @@ class ShardedSsdBackend(MatchBackend):
             self._flush_lookups(lookups, bursts, opens)
         if gathers:
             self._flush_gathers(gathers, bursts, opens)
-        self.stats.staged_bytes = self.store.staged_bytes
-        staged, self.store.staged_log = self.store.staged_log, []
-        if self.timeline is not None:
-            for a in staged:   # dirty/new planes restage in storage mode
-                c, _ = self.decompose(a)
-                self._burst(bursts, c).bus_storage_bytes += PAGE_BYTES
-            self.timeline.observe_flush(
-                [bursts[c] for c in sorted(bursts)])
+        with span(trace.FLUSH_ACCOUNT):
+            self.stats.staged_bytes = self.store.staged_bytes
+            staged, self.store.staged_log = self.store.staged_log, []
+            if self.timeline is not None:
+                for a in staged:   # dirty/new planes restage in storage mode
+                    c, _ = self.decompose(a)
+                    self._burst(bursts, c).bus_storage_bytes += PAGE_BYTES
+                self.timeline.observe_flush(
+                    [bursts[c] for c in sorted(bursts)])
 
     def _burst(self, bursts: dict[int, ChipBurst], chip: int) -> ChipBurst:
         return bursts.setdefault(chip, ChipBurst(chip))
@@ -568,82 +586,90 @@ class ShardedSsdBackend(MatchBackend):
         vf = self.reliability.vote_factor if self.reliability is not None \
             else 1
         n = self.n_chips
-        addrs: list[list[int]] = [[] for _ in range(n)]
-        page_rows: list[dict[int, int]] = [{} for _ in range(n)]
-        query_rows: list[dict[tuple, int]] = [{} for _ in range(n)]
-        q_pairs: list[list] = [[] for _ in range(n)]
-        m_pairs: list[list] = [[] for _ in range(n)]
-        placements = []                        # (chip, qi, pi)
-        for cmd, _ in searches:
-            c, _local = self.decompose(cmd.page_addr)
-            if cmd.page_addr not in page_rows[c]:
-                page_rows[c][cmd.page_addr] = len(addrs[c])
-                addrs[c].append(cmd.page_addr)
-            key = (cmd.query, cmd.mask)
-            if key not in query_rows[c]:
-                query_rows[c][key] = len(q_pairs[c])
-                q_pairs[c].append(cmd.query)
-                m_pairs[c].append(cmd.mask)
-            placements.append((c, query_rows[c][key],
-                               page_rows[c][cmd.page_addr]))
+        with span(trace.FLUSH_PLACE):
+            addrs: list[list[int]] = [[] for _ in range(n)]
+            page_rows: list[dict[int, int]] = [{} for _ in range(n)]
+            query_rows: list[dict[tuple, int]] = [{} for _ in range(n)]
+            q_pairs: list[list] = [[] for _ in range(n)]
+            m_pairs: list[list] = [[] for _ in range(n)]
+            placements = []                        # (chip, qi, pi)
+            for cmd, _ in searches:
+                c, _local = self.decompose(cmd.page_addr)
+                if cmd.page_addr not in page_rows[c]:
+                    page_rows[c][cmd.page_addr] = len(addrs[c])
+                    addrs[c].append(cmd.page_addr)
+                key = (cmd.query, cmd.mask)
+                if key not in query_rows[c]:
+                    query_rows[c][key] = len(q_pairs[c])
+                    q_pairs[c].append(cmd.query)
+                    m_pairs[c].append(cmd.mask)
+                placements.append((c, query_rows[c][key],
+                                   page_rows[c][cmd.page_addr]))
 
-        active = [c for c in range(n) if addrs[c]]
-        slot_of = {c: i for i, c in enumerate(active)}
-        n_pad = max(padded_rows(len(addrs[c]), self.page_block)
-                    for c in active)
-        q_pad = max(next_pow2(len(q_pairs[c])) for c in active)
-        c_pad = next_pow2(len(active))
+            active = [c for c in range(n) if addrs[c]]
+            slot_of = {c: i for i, c in enumerate(active)}
+            n_pad = max(padded_rows(len(addrs[c]), self.page_block)
+                        for c in active)
+            q_pad = max(next_pow2(len(q_pairs[c])) for c in active)
+            c_pad = next_pow2(len(active))
+            stacked = [(slot_of[c], qi, pi) for c, qi, pi in placements]
 
         # One staging pass over every chip's pages, then one (C, N) gather.
-        flat = [a for c in active for a in addrs[c]]
-        rows = self.store.rows_for(flat)
-        idx2d = np.zeros((c_pad, n_pad), np.int32)
-        off = 0
-        for i, c in enumerate(active):
-            k = len(addrs[c])
-            idx2d[i, :k] = rows[off:off + k]
-            off += k
-            chip = self.chips.chips[c]
-            chip.counters.array_reads += k     # one staged sense per page
-            b = self._burst(bursts, c)
-            b.senses += k * vf
-            b.bus_match_bytes += OPEN_OVERHEAD_BYTES * k
-        lo, hi, ids, seeds = self.store.take2d(idx2d)
-        q = np.zeros((c_pad, q_pad, 2), dtype=np.uint32)
-        m = np.zeros_like(q)
-        for i, c in enumerate(active):
-            q[i, :len(q_pairs[c])] = np.asarray(q_pairs[c], np.uint32)
-            m[i, :len(m_pairs[c])] = np.asarray(m_pairs[c], np.uint32)
+        with span(trace.FLUSH_OPERANDS):
+            flat = [a for c in active for a in addrs[c]]
+            rows = self.store.rows_for(flat)
+            idx2d = np.zeros((c_pad, n_pad), np.int32)
+            off = 0
+            for i, c in enumerate(active):
+                k = len(addrs[c])
+                idx2d[i, :k] = rows[off:off + k]
+                off += k
+            lo, hi, ids, seeds = self.store.take2d(idx2d)
+            q = np.zeros((c_pad, q_pad, 2), dtype=np.uint32)
+            m = np.zeros_like(q)
+            for i, c in enumerate(active):
+                q[i, :len(q_pairs[c])] = np.asarray(q_pairs[c], np.uint32)
+                m[i, :len(m_pairs[c])] = np.asarray(m_pairs[c], np.uint32)
 
         interp = self.interpret
         if interp is None:
             from repro.kernels import default_interpret
             interp = default_interpret()
-        out = _stacked_search(
-            lo, hi, q, m, ids, seeds, page_block=self.page_block,
-            use_kernel=self.use_kernel, interpret=interp)
+        with span(trace.FLUSH_LAUNCH, kind="search", rows=c_pad * n_pad):
+            out = _stacked_search(
+                lo, hi, q, m, ids, seeds, page_block=self.page_block,
+                use_kernel=self.use_kernel, interpret=interp)
 
-        self.stats.kernel_launches += 1
-        self.stats.staged_pages += len(flat)
-        self.stats.staged_queries += sum(len(q_pairs[c]) for c in active)
-        self.stats.searches += len(searches)
-        if len(searches) > 1:
-            self.stats.batched_searches += len(searches)
-        for cmd, _ in searches:
-            c, _local = self.decompose(cmd.page_addr)
-            b = self._burst(bursts, c)
-            b.matches += vf
-            b.bus_match_bytes += BITMAP_BYTES
-            b.pcie_bytes += BITMAP_BYTES + QUERY_BYTES
+        with span(trace.FLUSH_ACCOUNT):
+            for c in active:
+                k = len(addrs[c])
+                self.chips.chips[c].counters.array_reads += k  # one sense/page
+                b = self._burst(bursts, c)
+                b.senses += k * vf
+                b.bus_match_bytes += OPEN_OVERHEAD_BYTES * k
+            self.stats.kernel_launches += 1
+            self.stats.launched_rows += c_pad * n_pad
+            self.stats.staged_pages += len(flat)
+            self.stats.staged_queries += sum(len(q_pairs[c]) for c in active)
+            self.stats.searches += len(searches)
+            if len(searches) > 1:
+                self.stats.batched_searches += len(searches)
+            for cmd, _ in searches:
+                c, _local = self.decompose(cmd.page_addr)
+                b = self._burst(bursts, c)
+                b.matches += vf
+                b.bus_match_bytes += BITMAP_BYTES
+                b.pcie_bytes += BITMAP_BYTES + QUERY_BYTES
 
-        stacked = [(slot_of[c], qi, pi) for c, qi, pi in placements]
-
-        def tail(out=out, searches=searches, stacked=stacked,
-                 rel=self.reliability, opens=opens):
-            self.stats.result_bytes += resolve_search_responses(
-                self.chips, searches, stacked, np.asarray(out),
-                reliability=rel, opens=opens)
-        self._defer_all(searches, tail)
+            def tail(out=out, searches=searches, stacked=stacked,
+                     rel=self.reliability, opens=opens):
+                with span(trace.TAIL_FETCH):
+                    out = np.asarray(out)
+                self.stats.result_bytes += resolve_search_responses(
+                    self.chips, searches, stacked, out,
+                    reliability=rel, opens=opens)
+            self._defer_all(searches, tail, kind="search",
+                            flush=self.flush_seq)
 
     # --------------------------------------------------------------- plans
     def _flush_plans(self, plans, bursts, opens=None) -> None:
@@ -658,172 +684,195 @@ class ShardedSsdBackend(MatchBackend):
         split path would cross 64 B per pass per page.
         """
         n = self.n_chips
-        addrs: list[list[int]] = [[] for _ in range(n)]
-        page_rows: list[dict[int, int]] = [{} for _ in range(n)]
-        group_rows: list[dict[tuple, int]] = [{} for _ in range(n)]
-        groups: list[list[tuple]] = [[] for _ in range(n)]
         vf = self.reliability.vote_factor if self.reliability is not None \
             else 1
-        placements = []                        # (chip, gi, pi)
-        for cmd, _ in plans:
-            c, _local = self.decompose(cmd.page_addr)
-            if cmd.page_addr not in page_rows[c]:
-                page_rows[c][cmd.page_addr] = len(addrs[c])
-                addrs[c].append(cmd.page_addr)
-            key = (cmd.plan_include, cmd.plan_exclude)
-            if key not in group_rows[c]:
-                group_rows[c][key] = len(groups[c])
-                groups[c].append(key)
-            placements.append((c, group_rows[c][key],
-                               page_rows[c][cmd.page_addr]))
+        with span(trace.FLUSH_PLACE):
+            addrs: list[list[int]] = [[] for _ in range(n)]
+            page_rows: list[dict[int, int]] = [{} for _ in range(n)]
+            group_rows: list[dict[tuple, int]] = [{} for _ in range(n)]
+            groups: list[list[tuple]] = [[] for _ in range(n)]
+            placements = []                        # (chip, gi, pi)
+            for cmd, _ in plans:
+                c, _local = self.decompose(cmd.page_addr)
+                if cmd.page_addr not in page_rows[c]:
+                    page_rows[c][cmd.page_addr] = len(addrs[c])
+                    addrs[c].append(cmd.page_addr)
+                key = (cmd.plan_include, cmd.plan_exclude)
+                if key not in group_rows[c]:
+                    group_rows[c][key] = len(groups[c])
+                    groups[c].append(key)
+                placements.append((c, group_rows[c][key],
+                                   page_rows[c][cmd.page_addr]))
 
-        active = [c for c in range(n) if addrs[c]]
-        slot_of = {c: i for i, c in enumerate(active)}
-        n_pad = max(padded_rows(len(addrs[c]), self.page_block)
-                    for c in active)
-        g_pad = max(next_pow2(len(groups[c])) for c in active)
-        p_pad = next_pow2(max(max((len(i) + len(e) for i, e in groups[c]),
-                                  default=1) for c in active))
-        c_pad = next_pow2(len(active))
+            active = [c for c in range(n) if addrs[c]]
+            slot_of = {c: i for i, c in enumerate(active)}
+            n_pad = max(padded_rows(len(addrs[c]), self.page_block)
+                        for c in active)
+            g_pad = max(next_pow2(len(groups[c])) for c in active)
+            p_pad = next_pow2(max(max((len(i) + len(e) for i, e in groups[c]),
+                                      default=1) for c in active))
+            c_pad = next_pow2(len(active))
+            stacked = [(slot_of[c], gi, pi) for c, gi, pi in placements]
 
-        flat = [a for c in active for a in addrs[c]]
-        rows = self.store.rows_for(flat)
-        idx2d = np.zeros((c_pad, n_pad), np.int32)
-        off = 0
-        for i, c in enumerate(active):
-            k = len(addrs[c])
-            idx2d[i, :k] = rows[off:off + k]
-            off += k
-            chip = self.chips.chips[c]
-            chip.counters.array_reads += k     # one staged sense per page
-            b = self._burst(bursts, c)
-            b.senses += k * vf
-            b.bus_match_bytes += OPEN_OVERHEAD_BYTES * k
-        lo, hi, ids, seeds = self.store.take2d(idx2d)
-        q = np.zeros((c_pad, g_pad, p_pad, 2), dtype=np.uint32)
-        m = np.zeros_like(q)
-        f = np.zeros((c_pad, g_pad, p_pad), dtype=np.uint32)
-        for i, c in enumerate(active):
-            for gi, (inc, exc) in enumerate(groups[c]):
-                q[i, gi], m[i, gi], f[i, gi] = plan_pass_rows(inc, exc,
-                                                              p_pad)
+        with span(trace.FLUSH_OPERANDS):
+            flat = [a for c in active for a in addrs[c]]
+            rows = self.store.rows_for(flat)
+            idx2d = np.zeros((c_pad, n_pad), np.int32)
+            off = 0
+            for i, c in enumerate(active):
+                k = len(addrs[c])
+                idx2d[i, :k] = rows[off:off + k]
+                off += k
+            lo, hi, ids, seeds = self.store.take2d(idx2d)
+            q = np.zeros((c_pad, g_pad, p_pad, 2), dtype=np.uint32)
+            m = np.zeros_like(q)
+            f = np.zeros((c_pad, g_pad, p_pad), dtype=np.uint32)
+            for i, c in enumerate(active):
+                for gi, (inc, exc) in enumerate(groups[c]):
+                    q[i, gi], m[i, gi], f[i, gi] = plan_pass_rows(inc, exc,
+                                                                  p_pad)
 
         interp = self.interpret
         if interp is None:
             from repro.kernels import default_interpret
             interp = default_interpret()
-        out = _stacked_plan(
-            lo, hi, q, m, f, ids, seeds, page_block=self.page_block,
-            use_kernel=self.use_kernel, interpret=interp)
+        with span(trace.FLUSH_LAUNCH, kind="plan", rows=c_pad * n_pad):
+            out = _stacked_plan(
+                lo, hi, q, m, f, ids, seeds, page_block=self.page_block,
+                use_kernel=self.use_kernel, interpret=interp)
 
-        self.stats.kernel_launches += 1
-        self.stats.staged_pages += len(flat)
-        self.stats.staged_queries += sum(len(i) + len(e)
-                                         for c in active
-                                         for i, e in groups[c])
-        self.stats.plans += len(plans)
-        for cmd, _ in plans:
-            c, _local = self.decompose(cmd.page_addr)
-            b = self._burst(bursts, c)
-            b.matches += cmd.n_passes * vf     # every pass matches on-die
-            b.bus_match_bytes += BITMAP_BYTES  # ...but ONE bitmap crosses
-            b.pcie_bytes += BITMAP_BYTES + QUERY_BYTES * cmd.n_passes
+        with span(trace.FLUSH_ACCOUNT):
+            for c in active:
+                k = len(addrs[c])
+                self.chips.chips[c].counters.array_reads += k  # one sense/page
+                b = self._burst(bursts, c)
+                b.senses += k * vf
+                b.bus_match_bytes += OPEN_OVERHEAD_BYTES * k
+            self.stats.kernel_launches += 1
+            self.stats.launched_rows += c_pad * n_pad
+            self.stats.staged_pages += len(flat)
+            self.stats.staged_queries += sum(len(i) + len(e)
+                                             for c in active
+                                             for i, e in groups[c])
+            self.stats.plans += len(plans)
+            for cmd, _ in plans:
+                c, _local = self.decompose(cmd.page_addr)
+                b = self._burst(bursts, c)
+                b.matches += cmd.n_passes * vf  # every pass matches on-die
+                b.bus_match_bytes += BITMAP_BYTES  # ...but ONE bitmap crosses
+                b.pcie_bytes += BITMAP_BYTES + QUERY_BYTES * cmd.n_passes
 
-        stacked = [(slot_of[c], gi, pi) for c, gi, pi in placements]
-
-        def tail(out=out, plans=plans, stacked=stacked,
-                 rel=self.reliability, opens=opens):
-            self.stats.result_bytes += resolve_plan_responses(
-                self.chips, plans, stacked, np.asarray(out),
-                reliability=rel, opens=opens)
-        self._defer_all(plans, tail)
+            def tail(out=out, plans=plans, stacked=stacked,
+                     rel=self.reliability, opens=opens):
+                with span(trace.TAIL_FETCH):
+                    out = np.asarray(out)
+                self.stats.result_bytes += resolve_plan_responses(
+                    self.chips, plans, stacked, out,
+                    reliability=rel, opens=opens)
+            self._defer_all(plans, tail, kind="plan", flush=self.flush_seq)
 
     # -------------------------------------------------------------- lookups
     def _flush_lookups(self, lookups, bursts, opens=None) -> None:
         """Row-stacked fused burst across every chip: ONE launch."""
         vf = self.reliability.vote_factor if self.reliability is not None \
             else 1
-        key_addrs = [cmd.page_addr for cmd, _ in lookups]
-        val_addrs = [cmd.value_page for cmd, _ in lookups]
-        k_rows = self.store.rows_for(key_addrs)
-        v_rows = self.store.rows_for(val_addrs)
-        n = len(lookups)
-        n_pad = padded_rows(n, self.lookup_block)
-        klo, khi, kids, kseeds = self.store.take(k_rows, n_pad)
-        vlo, vhi, _, _ = self.store.take(v_rows, n_pad)
-        q = np.zeros((n_pad, 2), dtype=np.uint32)
-        m = np.full((n_pad, 2), 0xFFFFFFFF, dtype=np.uint32)  # pad rows miss
-        q[:n] = np.asarray([cmd.query for cmd, _ in lookups], np.uint32)
-        m[:n] = np.asarray([cmd.mask for cmd, _ in lookups], np.uint32)
+        with span(trace.FLUSH_PLACE):
+            key_addrs = [cmd.page_addr for cmd, _ in lookups]
+            val_addrs = [cmd.value_page for cmd, _ in lookups]
+            n = len(lookups)
+            n_pad = padded_rows(n, self.lookup_block)
+        with span(trace.FLUSH_OPERANDS):
+            k_rows = self.store.rows_for(key_addrs)
+            v_rows = self.store.rows_for(val_addrs)
+            klo, khi, kids, kseeds = self.store.take(k_rows, n_pad)
+            vlo, vhi, _, _ = self.store.take(v_rows, n_pad)
+            q = np.zeros((n_pad, 2), dtype=np.uint32)
+            m = np.full((n_pad, 2), 0xFFFFFFFF, dtype=np.uint32)  # pads miss
+            q[:n] = np.asarray([cmd.query for cmd, _ in lookups], np.uint32)
+            m[:n] = np.asarray([cmd.mask for cmd, _ in lookups], np.uint32)
 
-        bm, val, slots = sim_fused_lookup(
-            klo, khi, vlo, vhi, q, m, randomized=True,
-            key_ids=kids, key_seeds=kseeds, row_block=self.lookup_block,
-            use_kernel=self.use_kernel, interpret=self.interpret)
-        self.stats.kernel_launches += 1
-        self.stats.lookups += n
-        self.stats.staged_pages += len(set(key_addrs) | set(val_addrs))
-        self.stats.staged_queries += n
-        # Key pages re-sense vote_k times for majority voting; value pages
-        # sense once (the chunk read is verified by parity, not by vote).
-        for addrs, senses in ((set(key_addrs), vf), (set(val_addrs), 1)):
-            for a in addrs:                    # one open per unique page
-                c, _ = self.decompose(a)
-                b = self._burst(bursts, c)
-                b.senses += senses
-                b.bus_match_bytes += OPEN_OVERHEAD_BYTES
-        for cmd, _ in lookups:
-            kc, _ = self.decompose(cmd.page_addr)
-            vc, _ = self.decompose(cmd.value_page)
-            kb = self._burst(bursts, kc)
-            kb.matches += vf
-            kb.bus_match_bytes += BITMAP_BYTES
-            kb.pcie_bytes += BITMAP_BYTES + QUERY_BYTES
-            vb = self._burst(bursts, vc)
-            vb.bus_match_bytes += CHUNK_BYTES
-            vb.pcie_bytes += CHUNK_BYTES
+        with span(trace.FLUSH_LAUNCH, kind="lookup", rows=2 * n_pad):
+            bm, val, slots = sim_fused_lookup(
+                klo, khi, vlo, vhi, q, m, randomized=True,
+                key_ids=kids, key_seeds=kseeds, row_block=self.lookup_block,
+                use_kernel=self.use_kernel, interpret=self.interpret)
+        with span(trace.FLUSH_ACCOUNT):
+            self.stats.kernel_launches += 1
+            self.stats.launched_rows += 2 * n_pad
+            self.stats.lookups += n
+            self.stats.staged_pages += len(set(key_addrs) | set(val_addrs))
+            self.stats.staged_queries += n
+            # Key pages re-sense vote_k times for majority voting; value
+            # pages sense once (the chunk read is verified by parity, not
+            # by vote).
+            for addrs, senses in ((set(key_addrs), vf), (set(val_addrs), 1)):
+                for a in addrs:                    # one open per unique page
+                    c, _ = self.decompose(a)
+                    b = self._burst(bursts, c)
+                    b.senses += senses
+                    b.bus_match_bytes += OPEN_OVERHEAD_BYTES
+            for cmd, _ in lookups:
+                kc, _ = self.decompose(cmd.page_addr)
+                vc, _ = self.decompose(cmd.value_page)
+                kb = self._burst(bursts, kc)
+                kb.matches += vf
+                kb.bus_match_bytes += BITMAP_BYTES
+                kb.pcie_bytes += BITMAP_BYTES + QUERY_BYTES
+                vb = self._burst(bursts, vc)
+                vb.bus_match_bytes += CHUNK_BYTES
+                vb.pcie_bytes += CHUNK_BYTES
 
-        snap = snapshot_parities(self.chips, val_addrs)
+            snap = snapshot_parities(self.chips, val_addrs)
 
-        def tail(bm=bm, val=val, slots=slots, lookups=lookups, n=n,
-                 snap=snap, rel=self.reliability, opens=opens):
-            self.stats.result_bytes += resolve_lookup_responses(
-                self.chips, lookups, np.asarray(bm)[:n],
-                np.asarray(val)[:n], np.asarray(slots)[:n], snap,
-                reliability=rel, opens=opens)
-        self._defer_all(lookups, tail)
+            def tail(bm=bm, val=val, slots=slots, lookups=lookups, n=n,
+                     snap=snap, rel=self.reliability, opens=opens):
+                with span(trace.TAIL_FETCH):
+                    bm, val, slots = (np.asarray(bm)[:n], np.asarray(val)[:n],
+                                      np.asarray(slots)[:n])
+                self.stats.result_bytes += resolve_lookup_responses(
+                    self.chips, lookups, bm, val, slots, snap,
+                    reliability=rel, opens=opens)
+            self._defer_all(lookups, tail, kind="lookup",
+                            flush=self.flush_seq)
 
     # -------------------------------------------------------------- gathers
     def _flush_gathers(self, gathers, bursts, opens=None) -> None:
-        addrs = [cmd.page_addr for cmd, _ in gathers]
-        rows = self.store.rows_for(addrs)
-        n = len(gathers)
-        n_pad = padded_rows(n, self.page_block)
-        lo, hi, _, _ = self.store.take(rows, n_pad)
-        chunk_words = planes_to_chunk_words_xp(lo, hi, jnp)
-        bm = np.zeros((n_pad, 2), dtype=np.uint32)
-        bm[:n] = np.asarray([cmd.chunk_bitmap for cmd, _ in gathers],
-                            np.uint32)
-        out, _counts = sim_gather(chunk_words, bm,
-                                  max_out=CHUNKS_PER_PAGE,
-                                  page_block=self.page_block,
-                                  interpret=self.interpret,
-                                  use_kernel=self.use_kernel)
-        self.stats.kernel_launches += 1
-        self.stats.gathers += n
-        snap = snapshot_parities(self.chips, addrs)
+        with span(trace.FLUSH_PLACE):
+            addrs = [cmd.page_addr for cmd, _ in gathers]
+            n = len(gathers)
+            n_pad = padded_rows(n, self.page_block)
+        with span(trace.FLUSH_OPERANDS):
+            rows = self.store.rows_for(addrs)
+            lo, hi, _, _ = self.store.take(rows, n_pad)
+            chunk_words = planes_to_chunk_words_xp(lo, hi, jnp)
+            bm = np.zeros((n_pad, 2), dtype=np.uint32)
+            bm[:n] = np.asarray([cmd.chunk_bitmap for cmd, _ in gathers],
+                                np.uint32)
+        with span(trace.FLUSH_LAUNCH, kind="gather", rows=n_pad):
+            out, _counts = sim_gather(chunk_words, bm,
+                                      max_out=CHUNKS_PER_PAGE,
+                                      page_block=self.page_block,
+                                      interpret=self.interpret,
+                                      use_kernel=self.use_kernel)
+        with span(trace.FLUSH_ACCOUNT):
+            self.stats.kernel_launches += 1
+            self.stats.gathers += n
+            snap = snapshot_parities(self.chips, addrs)
 
-        def tail(out=out, gathers=gathers, n=n, snap=snap,
-                 rel=self.reliability, opens=opens):
-            self.stats.result_bytes += resolve_gather_responses(
-                self.chips, gathers, np.asarray(out)[:n], snap,
-                reliability=rel, opens=opens)
-        self._defer_all(gathers, tail)
-        for cmd, _ in gathers:
-            c, _local = self.decompose(cmd.page_addr)
-            k = int(popcount_words(
-                np.asarray(cmd.chunk_bitmap, np.uint32)).sum())
-            b = self._burst(bursts, c)
-            b.senses += 1
-            b.bus_match_bytes += CHUNK_BYTES * k
-            b.pcie_bytes += CHUNK_BYTES * k
+            def tail(out=out, gathers=gathers, n=n, snap=snap,
+                     rel=self.reliability, opens=opens):
+                with span(trace.TAIL_FETCH):
+                    out = np.asarray(out)[:n]
+                self.stats.result_bytes += resolve_gather_responses(
+                    self.chips, gathers, out, snap,
+                    reliability=rel, opens=opens)
+            self._defer_all(gathers, tail, kind="gather",
+                            flush=self.flush_seq)
+            for cmd, _ in gathers:
+                c, _local = self.decompose(cmd.page_addr)
+                k = int(popcount_words(
+                    np.asarray(cmd.chunk_bitmap, np.uint32)).sum())
+                b = self._burst(bursts, c)
+                b.senses += 1
+                b.bus_match_bytes += CHUNK_BYTES * k
+                b.pcie_bytes += CHUNK_BYTES * k

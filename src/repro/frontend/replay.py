@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import trace
 from repro.backend import as_backend
 from repro.buffer.writebuffer import WriteBuffer
 from repro.core.bits import SLOTS_PER_CHUNK, unpack_bitmap
@@ -34,6 +35,7 @@ from repro.core.page import mask_header_slots
 from repro.core.range_query import evaluate_plan_on_pages, exact_range
 from repro.reliability import (DegradedReadError, UncorrectableReadError,
                                require_clean)
+from repro.trace import span
 from repro.workload.ycsb import KEYS_PER_PAGE, Workload, value_page_of
 
 from .config import RunConfig
@@ -144,26 +146,31 @@ class ReplayCore:
 
     def resolve_burst(self) -> None:
         """Flush the open read burst (no-op when nothing is pending)."""
-        self._resolve()
+        if self.pending:
+            with span(trace.REPLAY_BURST):
+                self._resolve()
 
-    def _drain(self, lookups) -> None:
-        for qi, t in lookups:
-            try:
-                r = require_clean(t.result())
-            except UncorrectableReadError:
-                self.read_errors[qi] = True
-                continue
-            except DegradedReadError:
-                self.op_errors[qi] = True   # no live replica left
-                continue
-            if r.value_slot is None:
-                continue
-            self.out[qi] = int.from_bytes(r.value, "little")
-            self.hits[qi] = True
+    def _drain(self, flush: int, lookups) -> None:
+        """Read the answers of the burst that backend flush ``flush``
+        launched."""
+        with span(trace.REPLAY_DRAIN, flush=flush):
+            for qi, t in lookups:
+                try:
+                    r = require_clean(t.result())
+                except UncorrectableReadError:
+                    self.read_errors[qi] = True
+                    continue
+                except DegradedReadError:
+                    self.op_errors[qi] = True   # no live replica left
+                    continue
+                if r.value_slot is None:
+                    continue
+                self.out[qi] = int.from_bytes(r.value, "little")
+                self.hits[qi] = True
 
     def drain_inflight(self) -> None:
         while self._inflight:
-            self._drain(self._inflight.pop(0))
+            self._drain(*self._inflight.pop(0))
 
     def _resolve_burst_fused(self) -> None:
         """One submit_lookup per read: the whole burst is ONE launch.
@@ -184,9 +191,9 @@ class ReplayCore:
         self.pending.clear()
         backend.flush()
         self.flushes += 1
-        self._inflight.append(lookups)
+        self._inflight.append((getattr(backend, "flush_seq", 0), lookups))
         while len(self._inflight) > 1:
-            self._drain(self._inflight.pop(0))
+            self._drain(*self._inflight.pop(0))
 
     def _resolve_burst_split(self) -> None:
         """Search launch, host bitmap decode, then gather launch."""
@@ -262,6 +269,10 @@ class ReplayCore:
         resolved first so the plan flush stays a dedicated launch.
         Returns the touched pages (the event driver's timing footprint).
         """
+        with span(trace.REPLAY_SCAN):
+            return self._scan(qi)
+
+    def _scan(self, qi: int) -> list[int]:
         self.resolve_burst()
         wl = self.workload
         pages = self.scan_pages(qi)
@@ -336,11 +347,12 @@ class ReplayCore:
         programmed pages (empty when the buffer was clean)."""
         if self.wb is None or not self.wb.n_dirty:
             return []
-        self.resolve_burst()        # queued reads precede the programs
-        if self.reliability is not None:
-            self.drain_inflight()
-        pages = self.wb.dirty_pages
-        self.programs += self.wb.flush(self.backend)
+        with span(trace.REPLAY_WB_DRAIN):
+            self.resolve_burst()        # queued reads precede the programs
+            if self.reliability is not None:
+                self.drain_inflight()
+            pages = self.wb.dirty_pages
+            self.programs += self.wb.flush(self.backend)
         self.write_flushes += 1
         return pages
 

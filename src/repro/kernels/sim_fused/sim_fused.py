@@ -263,6 +263,7 @@ def sim_lookup_kernel(klo, khi, vlo, vhi, queries, masks, key_ids, key_seeds,
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="sim_lookup",
     )(jnp.asarray(klo, jnp.uint32), jnp.asarray(khi, jnp.uint32),
       jnp.asarray(vlo, jnp.uint32), jnp.asarray(vhi, jnp.uint32),
       jnp.asarray(queries, jnp.uint32), jnp.asarray(masks, jnp.uint32),

@@ -85,4 +85,5 @@ def sim_gather_kernel(chunks, bitmap_words, *, page_block: int = 16,
             jax.ShapeDtypeStruct((n, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="sim_gather",
     )(jnp.asarray(chunks, jnp.uint32), jnp.asarray(bitmap_words, jnp.uint32))

@@ -116,6 +116,7 @@ def _sim_plan_call(lo, hi, queries, masks, flags, page_ids, page_seeds, *,
         out_shape=jax.ShapeDtypeStruct((n_groups, n_pages, BITMAP_WORDS),
                                        jnp.uint32),
         interpret=interpret,
+        name="sim_plan",
     )(jnp.asarray(lo, jnp.uint32), jnp.asarray(hi, jnp.uint32),
       jnp.asarray(queries, jnp.uint32), jnp.asarray(masks, jnp.uint32),
       jnp.asarray(flags, jnp.uint32)[..., None],

@@ -106,6 +106,7 @@ def _sim_search_call(lo, hi, queries, masks, page_ids, page_seeds, *,
         out_shape=jax.ShapeDtypeStruct((n_queries, n_pages, BITMAP_WORDS),
                                        jnp.uint32),
         interpret=interpret,
+        name="sim_search",
     )(jnp.asarray(lo, jnp.uint32), jnp.asarray(hi, jnp.uint32),
       jnp.asarray(queries, jnp.uint32), jnp.asarray(masks, jnp.uint32),
       jnp.asarray(page_ids, jnp.uint32).reshape(-1, 1),

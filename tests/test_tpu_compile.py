@@ -51,3 +51,15 @@ def test_main_path_launch_compiles_for_v5e(one_chip, launch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16 * 2**30          # one v5e holds 16 GB of HBM
+
+
+@pytest.mark.parametrize("launch", ["sim_search", "sim_plan", "sim_lookup",
+                                    "sim_gather"])
+def test_main_path_kernel_carries_its_name(one_chip, launch):
+    """Each kernel's ``pallas_call`` is named, so its custom-call op in the
+    compiled program, and in a profiler trace, is ``%<launch>.<n>``."""
+    text = chip_smoke.main_path_launches(one_chip)[launch]().compile() \
+        .as_text()
+    calls = [line.split("=", 1)[0].strip() for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert calls and all(c.startswith(f"%{launch}.") for c in calls), calls
