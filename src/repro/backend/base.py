@@ -135,6 +135,9 @@ class BackendStats:
     plans: int = 0             # fused multi-pass plan commands resolved
     flushes: int = 0           # non-empty flush() calls
     kernel_launches: int = 0   # device launches (batched backend only)
+    operand_programs: int = 0  # compiled arena operand gathers: one per
+                               # launch (``PlaneStore.take``/``take2d``/
+                               # ``take_lookup``)
     staged_pages: int = 0      # page rows referenced across launches
     staged_queries: int = 0    # query rows staged across launches
     launched_rows: int = 0     # page-plane rows handed to search, plan and

@@ -437,6 +437,7 @@ class BatchedKernelBackend(MatchBackend):
 
         n_pages = padded_rows(len(addrs), self.page_block)
         lo, hi, page_ids, page_seeds = self.store.take(rows, n_pages)
+        self.stats.operand_programs += 1
         n_queries = len(q_pairs)
         q = np.zeros((next_pow2(n_queries), 2), dtype=np.uint32)
         m = np.zeros_like(q)
@@ -496,6 +497,7 @@ class BatchedKernelBackend(MatchBackend):
 
         n_pages = padded_rows(len(addrs), self.page_block)
         lo, hi, page_ids, page_seeds = self.store.take(rows, n_pages)
+        self.stats.operand_programs += 1
         p_pad = next_pow2(max(max(len(i) + len(e) for i, e in groups), 1))
         g_pad = next_pow2(len(groups))
         q = np.zeros((g_pad, p_pad, 2), dtype=np.uint32)
@@ -533,8 +535,9 @@ class BatchedKernelBackend(MatchBackend):
 
         n = len(lookups)
         n_pad = padded_rows(n, self.lookup_block)
-        klo, khi, kids, kseeds = self.store.take(k_rows, n_pad)
-        vlo, vhi, _, _ = self.store.take(v_rows, n_pad)
+        klo, khi, kids, kseeds, vlo, vhi = self.store.take_lookup(
+            k_rows, v_rows, n_pad)
+        self.stats.operand_programs += 1
         q = np.zeros((n_pad, 2), dtype=np.uint32)
         m = np.full((n_pad, 2), 0xFFFFFFFF, dtype=np.uint32)  # pad rows miss
         q[:n] = np.asarray([cmd.query for cmd, _ in lookups], np.uint32)
@@ -567,6 +570,7 @@ class BatchedKernelBackend(MatchBackend):
         n = len(gathers)
         n_pad = padded_rows(n, self.page_block)
         lo, hi, _, _ = self.store.take(rows, n_pad)
+        self.stats.operand_programs += 1
         chunk_words = planes_to_chunk_words_xp(lo, hi, jnp)
         bm = np.zeros((n_pad, 2), dtype=np.uint32)
         bm[:n] = np.asarray([cmd.chunk_bitmap for cmd, _ in gathers],

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import weakref
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -51,6 +52,32 @@ def padded_rows(n: int, block: int) -> int:
     every distinct burst size.
     """
     return block * next_pow2(-(-n // block))
+
+
+def _rows(plane, ridx):
+    """``plane[ridx]`` for row indices known to be in range: the gather
+    drops the negative-index fix-up and the out-of-bounds clamp."""
+    return plane.at[ridx].get(mode="promise_in_bounds",
+                              wrap_negative_indices=False)
+
+
+@jax.jit
+def _gather_operands(lo, hi, ids, seeds, ridx):
+    """Arena rows ``ridx`` (any index shape) of all four planes, as ONE
+    compiled program: eager indexing would dispatch several small device
+    programs per plane, and that host dispatch, not the bytes, is what an
+    operand gather costs."""
+    return (_rows(lo, ridx), _rows(hi, ridx),
+            _rows(ids, ridx)[..., 0], _rows(seeds, ridx)[..., 0])
+
+
+@jax.jit
+def _gather_lookup_operands(lo, hi, ids, seeds, ridx):
+    """A lookup burst's key rows ``ridx[0]`` (all four planes) and value
+    rows ``ridx[1]`` (word planes only), as ONE compiled program."""
+    return (_rows(lo, ridx[0]), _rows(hi, ridx[0]),
+            _rows(ids, ridx[0])[..., 0], _rows(seeds, ridx[0])[..., 0],
+            _rows(lo, ridx[1]), _rows(hi, ridx[1]))
 
 
 class PlaneStore:
@@ -191,6 +218,10 @@ class PlaneStore:
         self.staged_bytes += len(addrs) * PAGE_BYTES
 
     # ----------------------------------------------------------------- access
+    # Each access method is one call of a compiled gather with a host-built
+    # index array, whose rows come from ``rows_for`` and so are in range.
+    # Its compile cache follows the arena capacity and the padded index
+    # shape, both powers of two.
     def take(self, rows: np.ndarray, pad_to: int):
         """Device-side row gather, padded to ``pad_to`` rows (repeats row 0).
 
@@ -199,18 +230,30 @@ class PlaneStore:
         """
         r = np.zeros(pad_to, np.int32)
         r[:len(rows)] = rows
-        ridx = jnp.asarray(r)
-        return (self._lo[ridx], self._hi[ridx],
-                self._ids[ridx, 0], self._seeds[ridx, 0])
+        return _gather_operands(self._lo, self._hi, self._ids, self._seeds, r)
 
     def take2d(self, rows: np.ndarray):
-        """Row gather for a (C, R) index matrix, in four device ops total.
+        """Row gather for a (C, R) index matrix, in one device program.
 
         Returns (lo (C, R, 512), hi (C, R, 512), ids (C, R), seeds (C, R)).
         This is how the sharded backend stacks every chip's operand rows
         for its single vmapped launch without a per-chip gather+stack
-        cascade (device dispatch on the interpret path is the bottleneck).
+        cascade.
         """
-        ridx = jnp.asarray(np.asarray(rows, np.int32))
-        return (self._lo[ridx], self._hi[ridx],
-                self._ids[ridx, 0], self._seeds[ridx, 0])
+        return _gather_operands(self._lo, self._hi, self._ids, self._seeds,
+                                np.asarray(rows, np.int32))
+
+    def take_lookup(self, key_rows: np.ndarray, value_rows: np.ndarray,
+                    pad_to: int):
+        """A lookup burst's key and value rows in one device program, each
+        padded to ``pad_to`` rows (repeats row 0).
+
+        Returns (klo, khi (P, 512), kids, kseeds (P,), vlo, vhi (P, 512)):
+        what ``take`` returns for the key rows, and the word planes of the
+        value rows.
+        """
+        r = np.zeros((2, pad_to), np.int32)
+        r[0, :len(key_rows)] = key_rows
+        r[1, :len(value_rows)] = value_rows
+        return _gather_lookup_operands(self._lo, self._hi, self._ids,
+                                       self._seeds, r)

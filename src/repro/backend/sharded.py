@@ -625,6 +625,7 @@ class ShardedSsdBackend(MatchBackend):
                 idx2d[i, :k] = rows[off:off + k]
                 off += k
             lo, hi, ids, seeds = self.store.take2d(idx2d)
+            self.stats.operand_programs += 1
             q = np.zeros((c_pad, q_pad, 2), dtype=np.uint32)
             m = np.zeros_like(q)
             for i, c in enumerate(active):
@@ -724,6 +725,7 @@ class ShardedSsdBackend(MatchBackend):
                 idx2d[i, :k] = rows[off:off + k]
                 off += k
             lo, hi, ids, seeds = self.store.take2d(idx2d)
+            self.stats.operand_programs += 1
             q = np.zeros((c_pad, g_pad, p_pad, 2), dtype=np.uint32)
             m = np.zeros_like(q)
             f = np.zeros((c_pad, g_pad, p_pad), dtype=np.uint32)
@@ -784,8 +786,9 @@ class ShardedSsdBackend(MatchBackend):
         with span(trace.FLUSH_OPERANDS):
             k_rows = self.store.rows_for(key_addrs)
             v_rows = self.store.rows_for(val_addrs)
-            klo, khi, kids, kseeds = self.store.take(k_rows, n_pad)
-            vlo, vhi, _, _ = self.store.take(v_rows, n_pad)
+            klo, khi, kids, kseeds, vlo, vhi = self.store.take_lookup(
+                k_rows, v_rows, n_pad)
+            self.stats.operand_programs += 1
             q = np.zeros((n_pad, 2), dtype=np.uint32)
             m = np.full((n_pad, 2), 0xFFFFFFFF, dtype=np.uint32)  # pads miss
             q[:n] = np.asarray([cmd.query for cmd, _ in lookups], np.uint32)
@@ -844,6 +847,7 @@ class ShardedSsdBackend(MatchBackend):
         with span(trace.FLUSH_OPERANDS):
             rows = self.store.rows_for(addrs)
             lo, hi, _, _ = self.store.take(rows, n_pad)
+            self.stats.operand_programs += 1
             chunk_words = planes_to_chunk_words_xp(lo, hi, jnp)
             bm = np.zeros((n_pad, 2), dtype=np.uint32)
             bm[:n] = np.asarray([cmd.chunk_bitmap for cmd, _ in gathers],

@@ -20,7 +20,7 @@ from jax.profiler import TraceAnnotation as span
 FLUSH = "sim.flush"                    # flush=<flush_seq>
 FLUSH_PROGRAM = "sim.flush.program"    # grouped programs + arena restage
 FLUSH_PLACE = "sim.flush.place"        # queues, failover, command placement
-FLUSH_OPERANDS = "sim.flush.operands"  # rows_for, take/take2d, operand arrays
+FLUSH_OPERANDS = "sim.flush.operands"  # rows_for, compiled gather, operands
 FLUSH_LAUNCH = "sim.flush.launch"      # kind=<lookup|plan|search|gather>, rows
 FLUSH_ACCOUNT = "sim.flush.account"    # ChipBurst records, parities, stats
 STAGE = "sim.stage"                    # PlaneStore._stage, rows=<pages>
