@@ -10,6 +10,8 @@ KIND = "fault"
 
 
 def applies(cell) -> bool:
+    if cell.config["family"] != "ycsb":     # whose traffic keys it reads
+        return False
     t = cell.traffic
     return t["read_proportion"] + t["scan_proportion"] > 0
 

@@ -7,7 +7,8 @@ KIND = "fault"
 
 
 def applies(cell) -> bool:
-    return (cell.config["run_config"]["preset"] == "buffered"
+    return (cell.config["family"] == "ycsb"
+            and cell.config["run_config"]["preset"] == "buffered"
             and cell.traffic["update_proportion"] > 0)
 
 

@@ -1,13 +1,17 @@
 """The range-plan kernel's share of its HBM roofline: the bytes the
-window's plan launches require (``bench/roofline.py``) over the chip's
-peak bandwidth, divided by the device time of the kernel's custom-call
-ops inside ``jit__stacked_plan`` programs in the trace."""
+window's plan launches require (``bench/roofline.py``, the family's
+``kernel_bytes``) over the chip's peak bandwidth, divided by the device
+time of the kernel's custom-call ops inside ``jit__stacked_plan``
+programs in the trace."""
 from bench import roofline
+
+PROGRAM = "jit__stacked_plan"
 
 
 def read(run):
-    if run.trace is None or not run.plan_launches:
+    launches, required = run.kernel_bytes.get(PROGRAM, (0, 0))
+    if run.trace is None or not launches:
         return None
     return roofline.share_percent(
-        run.plan_bytes, run.trace.kernel_seconds("jit__stacked_plan"),
+        required, run.trace.kernel_seconds(PROGRAM),
         run.peaks["hbm_bytes_per_s"])

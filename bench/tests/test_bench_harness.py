@@ -99,9 +99,10 @@ def test_oracle_flags_a_corrupted_read_and_scan():
 
 
 def test_warmup_scans_cover_every_plan_shape():
-    traffic = harness.load_cell("ycsb_e-10m").traffic
+    cell = harness.load_cell("ycsb_e-10m")
+    traffic = cell.traffic
     n_keys = 19842 * ycsb.KEYS_PER_PAGE
-    scans = harness.warmup_scans(traffic, n_keys, seed=9)
+    scans = cell.family.warmup_scans(traffic, n_keys, seed=9)
     sigs = set()
     for k, n in scans:
         lo, hi = k + 1, k + 1 + n
