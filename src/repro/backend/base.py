@@ -152,6 +152,10 @@ class BackendStats:
     programs_coalesced: int = 0  # queued programs absorbed by a later
                                # program of the same page before the flush
                                # (last-wins; the page is programmed once)
+    gathered_chunks: int = 0   # chunks the gather commands select
+    gather_fetched_bytes: int = 0  # bytes the gather tails copy device->host,
+                               # padding included (the whole (rows, 64, 16)
+                               # launch output)
     result_bytes: int = 0      # exact device->host result payload: 64 B per
                                # search/plan bitmap (per unique launch cell
                                # on kernel backends — dedup'd commands share

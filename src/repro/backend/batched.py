@@ -582,10 +582,13 @@ class BatchedKernelBackend(MatchBackend):
                                   use_kernel=self.use_kernel)
         self.stats.kernel_launches += 1
         self.stats.gathers += n
+        self.stats.gathered_chunks += int(popcount_words(bm[:n]).sum())
         snap = snapshot_parities(self.chips, addrs)
 
         def tail(out=out, gathers=gathers, n=n, snap=snap,
                  rel=self.reliability, opens=opens):
+            out = np.asarray(out)
+            self.stats.gather_fetched_bytes += out.nbytes
             self.stats.result_bytes += resolve_gather_responses(
-                self.chips, gathers, np.asarray(out)[:n], snap, rel, opens)
+                self.chips, gathers, out[:n], snap, rel, opens)
         self._defer_all(gathers, tail)

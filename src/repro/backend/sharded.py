@@ -866,7 +866,9 @@ class ShardedSsdBackend(MatchBackend):
             def tail(out=out, gathers=gathers, n=n, snap=snap,
                      rel=self.reliability, opens=opens):
                 with span(trace.TAIL_FETCH):
-                    out = np.asarray(out)[:n]
+                    out = np.asarray(out)
+                self.stats.gather_fetched_bytes += out.nbytes
+                out = out[:n]
                 self.stats.result_bytes += resolve_gather_responses(
                     self.chips, gathers, out, snap,
                     reliability=rel, opens=opens)
@@ -876,6 +878,7 @@ class ShardedSsdBackend(MatchBackend):
                 c, _local = self.decompose(cmd.page_addr)
                 k = int(popcount_words(
                     np.asarray(cmd.chunk_bitmap, np.uint32)).sum())
+                self.stats.gathered_chunks += k
                 b = self._burst(bursts, c)
                 b.senses += 1
                 b.bus_match_bytes += CHUNK_BYTES * k

@@ -12,7 +12,7 @@ import dataclasses
 import numpy as np
 
 from .range_query import (MaskedQuery, RangePlan, approximate_range,
-                          exact_range)
+                          conjunctive_range, exact_range)
 
 U64 = 0xFFFFFFFFFFFFFFFF
 
@@ -81,3 +81,15 @@ class RowCodec:
         shift, width = self.shifts[name], self.widths[name]
         fn = exact_range if exact else approximate_range
         return fn(lo, hi, shift=shift, width=width)
+
+    def where(self, predicates: dict[str, tuple[int, int]]) -> RangePlan:
+        """Conjunction of ranges ``lo <= column < hi``, one per named column,
+        as one exact plan (``conjunctive_range``).  The named column that
+        sorts first in the packing gives the include passes; each other
+        one excludes its complement."""
+        unknown = set(predicates) - set(self.widths)
+        if unknown:
+            raise KeyError(f"no columns {sorted(unknown)} in the codec")
+        return conjunctive_range(
+            [(*predicates[c.name], self.shifts[c.name], c.width)
+             for c in self.columns if c.name in predicates])

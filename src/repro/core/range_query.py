@@ -22,6 +22,8 @@ from typing import TYPE_CHECKING, Sequence
 
 import numpy as np
 
+from repro.kernels.sim_plan.sim_plan import MAX_PASSES
+
 from .commands import Command
 
 if TYPE_CHECKING:                                    # avoid core -> backend cycle
@@ -175,6 +177,38 @@ def exact_range(lo: int, hi: int, *, shift: int = 0,
         blocks.append(prefix_query(cur, s, shift, width))
         cur += 1 << s
     return RangePlan(include=tuple(blocks), exact=True)
+
+
+def conjunctive_range(fields: Sequence[tuple[int, int, int, int]]
+                      ) -> RangePlan:
+    """Exact plan for a conjunction of ranges over disjoint packed fields.
+
+    ``fields`` holds ``(lo, hi, shift, width)`` per field, each the range
+    ``lo <= field < hi``.  The first field's exact prefix blocks are the
+    include passes.  Every other field's complement inside its own bits,
+    ``[0, lo)`` and ``[hi, 2**width)``, decomposes the same way into
+    exclude passes.  A key lies in the first range and in no complement
+    exactly when it lies in every range, so the plan is exact.  Raises
+    ValueError for an empty range, or for a plan of more than the plan
+    kernel's ``MAX_PASSES``, which one ``Op.PLAN`` cannot carry.
+    """
+    if not fields:
+        raise ValueError("a conjunction needs at least one field")
+    (lo, hi, shift, width), *rest = fields
+    include = exact_range(lo, hi, shift=shift, width=width).include
+    exclude: list[MaskedQuery] = []
+    for lo, hi, shift, width in rest:
+        if not (0 <= lo < hi <= (1 << width)):
+            raise ValueError((lo, hi, width))
+        for a, b in ((0, lo), (hi, 1 << width)):
+            if a < b:
+                exclude += exact_range(a, b, shift=shift,
+                                       width=width).include
+    plan = RangePlan(include=include, exclude=tuple(exclude), exact=True)
+    if plan.n_passes > MAX_PASSES:
+        raise ValueError(f"the conjunction needs {plan.n_passes} passes; "
+                         f"one plan holds at most {MAX_PASSES}")
+    return plan
 
 
 def false_positive_bound(plan: RangePlan, lo: int, hi: int,

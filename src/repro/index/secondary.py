@@ -2,8 +2,9 @@
 
 Rows are packed into 8-byte keys by a RowCodec (column -> bit range).  A
 column predicate becomes one masked search per page (point) or the §V-C
-range plan (range); gather returns only the matching encoded rows, from
-which the host decodes e.g. the user id.
+range plan (range), and ranges over several columns one exact conjunctive
+plan (``select_where``); gather returns only the matching encoded rows,
+from which the host decodes e.g. the user id.
 
 Predicates execute through a MatchBackend: every page's plan command is
 enqueued and flushed together, so a table scan is one batched launch (and
@@ -20,6 +21,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import trace
 from repro.backend import MatchBackend, as_backend
 from repro.core.bits import (SLOTS_PER_CHUNK, chunk_bitmap_from_slot_bitmap,
                              pair_to_u64, unpack_bitmap)
@@ -28,8 +30,14 @@ from repro.core.commands import Command
 from repro.core.page import mask_header_slots
 from repro.core.range_query import RangePlan, evaluate_plan_on_pages
 from repro.reliability import require_clean
+from repro.trace import span
 
 ROWS_PER_PAGE = 504
+
+
+class IncompleteGatherError(RuntimeError):
+    """A gather returned fewer chunks than its chunk bitmap selected, so
+    matching rows cannot be read back."""
 
 
 class SimSecondaryIndex:
@@ -62,68 +70,92 @@ class SimSecondaryIndex:
         return [self.first_page + p for p in range(self.n_pages)]
 
     def _collect_pages(self, bitmaps: np.ndarray) -> np.ndarray:
-        """Gather matching rows of all pages -> decoded uint64 keys.
+        """Gather matching rows of all pages -> encoded uint64 keys, in page
+        and slot order.
 
         Slots past a page's row count are vacant (all-ones sentinel) and
         can alias masked predicates (e.g. any column test with all-set
         bits), so the host strips them — the same software-side
         responsibility as the header-chunk mask.  All gathers are enqueued
-        before one flush.
+        before one flush.  Raises IncompleteGatherError when a selected
+        chunk did not come back.
         """
-        pending = []                       # (slots, ticket)
-        for p, bitmap_words in enumerate(bitmaps):
-            page = self.first_page + p
-            bitmap = mask_header_slots(bitmap_words)
-            slots = np.nonzero(unpack_bitmap(bitmap, 512))[0]
-            slots = slots[slots < SLOTS_PER_CHUNK + self._rows_in_page[p]]
-            if slots.size == 0:
-                continue
-            cb = int(pair_to_u64(*chunk_bitmap_from_slot_bitmap(bitmap)))
-            pending.append((slots, self.backend.submit_gather(
-                Command.gather(page, cb))))
+        pending = []                       # (page, slots, ticket)
+        with span(trace.SELECT_COLLECT):
+            for p, bitmap_words in enumerate(bitmaps):
+                page = self.first_page + p
+                bitmap = mask_header_slots(bitmap_words)
+                slots = np.nonzero(unpack_bitmap(bitmap, 512))[0]
+                slots = slots[slots < SLOTS_PER_CHUNK + self._rows_in_page[p]]
+                if slots.size == 0:
+                    continue
+                cb = int(pair_to_u64(*chunk_bitmap_from_slot_bitmap(bitmap)))
+                pending.append((page, slots, self.backend.submit_gather(
+                    Command.gather(page, cb))))
         self.backend.flush()
 
         rows = []
-        for slots, ticket in pending:
-            g = require_clean(ticket.result())
-            self.io_chunk_bytes += 64 * len(g.chunk_ids)
-            chunk_pos = {int(c): j for j, c in enumerate(g.chunk_ids)}
-            out = np.zeros(slots.size, dtype=np.uint64)
-            for i, s in enumerate(slots):
-                c, off = int(s) // SLOTS_PER_CHUNK, \
-                    (int(s) % SLOTS_PER_CHUNK) * 8
-                out[i] = int.from_bytes(
-                    bytes(g.chunks[chunk_pos[c]][off:off + 8]), "little")
-            rows.append(out)
+        with span(trace.SELECT_DECODE):
+            for page, slots, ticket in pending:
+                g = require_clean(ticket.result())
+                self.io_chunk_bytes += 64 * len(g.chunk_ids)
+                chunk_pos = {int(c): j for j, c in enumerate(g.chunk_ids)}
+                missing = set((slots // SLOTS_PER_CHUNK).tolist()) \
+                    - set(chunk_pos)
+                if missing:
+                    raise IncompleteGatherError(
+                        f"page {page}: chunks {sorted(missing)} not gathered")
+                out = np.zeros(slots.size, dtype=np.uint64)
+                for i, s in enumerate(slots):
+                    c, off = int(s) // SLOTS_PER_CHUNK, \
+                        (int(s) % SLOTS_PER_CHUNK) * 8
+                    out[i] = int.from_bytes(
+                        bytes(g.chunks[chunk_pos[c]][off:off + 8]), "little")
+                rows.append(out)
         return (np.concatenate(rows) if rows
                 else np.zeros(0, dtype=np.uint64))
 
+    def _select(self, plan: RangePlan) -> np.ndarray:
+        """Rows the plan selects: one ``Op.PLAN`` per page in one flush (all
+        passes accumulate in-latch and 64 B per page crosses the bus, no
+        matter how many passes), then the matching rows gathered."""
+        with span(trace.SELECT, pages=self.n_pages, passes=plan.n_passes):
+            bitmaps = evaluate_plan_on_pages(self.backend, plan,
+                                             self._page_addrs())
+            self.io_bitmap_bytes += 64 * self.n_pages   # combined, pass-free
+            return self._collect_pages(bitmaps)
+
     def select_equals(self, column: str, value: int) -> np.ndarray:
         """Fig 9: e.g. all rows with gender == female -> encoded rows."""
-        mq = self.codec.equals(column, value)
-        plan = RangePlan(include=(mq,))
-        bitmaps = evaluate_plan_on_pages(self.backend, plan,
-                                         self._page_addrs())
-        self.io_bitmap_bytes += 64 * self.n_pages
-        return self._collect_pages(bitmaps)
+        return self._select(RangePlan(include=(self.codec.equals(column,
+                                                                 value),)))
 
     def select_range(self, column: str, lo: int, hi: int, *,
                      exact: bool = True) -> np.ndarray:
         """Fig 10: lo <= column < hi via the masked-equality range plan.
 
-        The whole predicate is ONE ``Op.PLAN`` per page: all passes
-        accumulate in-latch and 64 B per page crosses the bus, no matter
-        how many passes the decomposition needs.  With ``exact=False``
-        the one-pass-per-bound approximate plan is used and the
-        (superset) result is refined on the host — the workflow the
-        paper proposes for analytical scans.
+        Exact, it is ``select_where`` on one column.  With ``exact=False``
+        the one-pass-per-bound approximate plan is used and the (superset)
+        result is refined on the host — the workflow the paper proposes
+        for analytical scans.
         """
-        plan: RangePlan = self.codec.range(column, lo, hi, exact=exact)
-        bitmaps = evaluate_plan_on_pages(self.backend, plan,
-                                         self._page_addrs())
-        self.io_bitmap_bytes += 64 * self.n_pages   # combined, pass-free
-        got = self._collect_pages(bitmaps)
-        if not exact and got.size:
+        if exact:
+            return self.select_where({column: (lo, hi)})
+        got = self._select(self.codec.range(column, lo, hi, exact=False))
+        if got.size:
             vals = self.codec.decode_rows(got, column)
             got = got[(vals >= lo) & (vals < hi)]   # host-side refinement
         return got
+
+    def select_where(self, predicates: dict[str, tuple[int, int]]
+                     ) -> np.ndarray:
+        """Rows where every ``lo <= column < hi`` of ``predicates`` holds,
+        as encoded keys in page and slot order (e.g. TPC-H Q6's shipdate,
+        discount and quantity ranges).
+
+        The conjunction is one exact plan (``RowCodec.where``): one
+        ``Op.PLAN`` per page in one flush, then one gather per page with a
+        match in one flush, then the rows are read out of the gathered
+        chunks.  Nothing is refined on the host.
+        """
+        return self._select(self.codec.where(predicates))

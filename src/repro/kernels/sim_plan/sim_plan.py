@@ -25,7 +25,8 @@ from different chips.  Plans ride a *group* axis: the grid is
 (1, P) block of the (G, P) flags would break the TPU's (8, 128) tiling).
 
 VMEM per step ~= 2 * PB * 2 KiB (planes) + P * PB * 2 KiB (pass-match
-intermediate); the default PB=8 keeps a 128-pass plan at ~2 MiB.
+intermediate); the default PB=8 keeps a 128-pass plan at ~2 MiB, the
+most passes one plan may hold (``MAX_PASSES``).
 """
 from __future__ import annotations
 
@@ -46,6 +47,7 @@ BITMAP_WORDS = 16
 PASS_PAD = 0        # padding row — contributes to neither accumulator
 PASS_INCLUDE = 1    # OR into the include accumulator
 PASS_EXCLUDE = 2    # OR into the exclude accumulator (AND-NOT at the end)
+MAX_PASSES = 128    # passes one plan may hold (VMEM at the default PB)
 
 
 def _plan_kernel(lo_ref, hi_ref, q_ref, m_ref, f_ref, page_ref, seed_ref,

@@ -4,8 +4,8 @@
 host span into the profiler's trace, on the same clock as the device's
 events, and costs under a microsecond when no profiler session runs.
 The session is the switch; there is no other.  Spans sit at the layer
-boundaries of the read, scan and write paths, never inside a jitted
-function and never on a per-op path that does no device work.
+boundaries of the read, scan, write and selection paths, never inside a
+jitted function and never on a per-op path that does no device work.
 
 The flush phases are siblings that together cover ``ShardedSsdBackend.flush``;
 what they leave out is the flush span's self time.  ``flush`` metadata is
@@ -26,6 +26,11 @@ FLUSH_ACCOUNT = "sim.flush.account"    # ChipBurst records, parities, stats
 STAGE = "sim.stage"                    # PlaneStore._stage, rows=<pages>
 TAIL = "sim.tail"                      # LazyResultBatch.run, flush=, kind=
 TAIL_FETCH = "sim.tail.fetch"          # device->host copy of launch outputs
+
+# Secondary index (index/secondary.py).
+SELECT = "sim.select"                  # one selection, pages=, passes=
+SELECT_COLLECT = "sim.select.collect"  # plan bitmaps -> gather commands
+SELECT_DECODE = "sim.select.decode"    # gathered chunks -> encoded rows
 
 # Replay frontend (frontend/replay.py).
 REPLAY_BURST = "sim.replay.burst"      # ReplayCore.resolve_burst
