@@ -28,6 +28,18 @@ class Op(enum.Enum):
     ERASE = "erase"
 
 
+def plan_passes(items) -> tuple:
+    """Plan passes in ``Command.plan``'s wire format: a tuple of
+    ``((q_lo, q_hi), (m_lo, m_hi))`` word pairs.  Items are as
+    ``Command.plan`` takes them; a caller planning many pages converts
+    once and hands the tuple to every page's command."""
+    out = []
+    for it in items:
+        q, mk = (it.query, it.mask) if hasattr(it, "query") else it
+        out.append((u64_to_pair(q), u64_to_pair(mk)))
+    return tuple(out)
+
+
 @dataclasses.dataclass
 class Command:
     op: Op
@@ -79,14 +91,8 @@ class Command:
         latches; one combined bitmap crosses the bus instead of one per
         pass.  Items are ``(query_u64, mask_u64)`` pairs or any object
         with ``query``/``mask`` attributes (``range_query.MaskedQuery``)."""
-        def _pairs(items):
-            out = []
-            for it in items:
-                q, mk = (it.query, it.mask) if hasattr(it, "query") else it
-                out.append((u64_to_pair(q), u64_to_pair(mk)))
-            return tuple(out)
-        return Command(Op.PLAN, page_addr, plan_include=_pairs(include),
-                       plan_exclude=_pairs(exclude), **kw)
+        return Command(Op.PLAN, page_addr, plan_include=plan_passes(include),
+                       plan_exclude=plan_passes(exclude), **kw)
 
     @property
     def n_passes(self) -> int:

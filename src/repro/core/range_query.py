@@ -24,7 +24,7 @@ import numpy as np
 
 from repro.kernels.sim_plan.sim_plan import MAX_PASSES
 
-from .commands import Command
+from .commands import Command, Op, plan_passes
 
 if TYPE_CHECKING:                                    # avoid core -> backend cycle
     from repro.backend.base import MatchBackend
@@ -80,11 +80,13 @@ def evaluate_plan_on_pages(backend: "MatchBackend", plan: RangePlan,
     ships one combined 64 B bitmap per page — device->host result bytes
     shrink by the pass count versus the per-pass path
     (:func:`evaluate_plan_per_pass`).  Returns the combined
-    (len(page_addrs), 16) uint32 slot bitmaps.
+    (len(page_addrs), 16) uint32 slot bitmaps.  The passes are converted
+    to word pairs once; every page's command shares the tuples.
     """
     from repro.reliability import require_clean
-    tickets = [backend.submit_plan(Command.plan(p, plan.include,
-                                                plan.exclude))
+    include, exclude = plan_passes(plan.include), plan_passes(plan.exclude)
+    tickets = [backend.submit_plan(Command(Op.PLAN, p, plan_include=include,
+                                           plan_exclude=exclude))
                for p in page_addrs]
     backend.flush()
     out = np.zeros((len(page_addrs), 16), dtype=np.uint32)

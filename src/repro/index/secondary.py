@@ -23,8 +23,8 @@ import numpy as np
 
 from repro import trace
 from repro.backend import MatchBackend, as_backend
-from repro.core.bits import (SLOTS_PER_CHUNK, chunk_bitmap_from_slot_bitmap,
-                             pair_to_u64, unpack_bitmap)
+from repro.core.bits import (CHUNK_BYTES, CHUNKS_PER_PAGE, SLOTS_PER_CHUNK,
+                             SLOTS_PER_PAGE)
 from repro.core.bitweaving import RowCodec
 from repro.core.commands import Command
 from repro.core.page import mask_header_slots
@@ -47,6 +47,7 @@ class SimSecondaryIndex:
         self.first_page = first_page
         self.n_pages = 0
         self.n_rows = 0
+        self._rows_in_page = np.zeros(0, dtype=np.int64)
         self.io_bitmap_bytes = 0
         self.io_chunk_bytes = 0
 
@@ -57,12 +58,12 @@ class SimSecondaryIndex:
     def load_rows(self, rows: dict[str, np.ndarray]) -> None:
         keys = self.codec.encode_rows(rows)
         self.n_rows = len(keys)
-        self._rows_in_page: list[int] = []
-        for start in range(0, len(keys), ROWS_PER_PAGE):
+        starts = np.arange(0, len(keys), ROWS_PER_PAGE)
+        self._rows_in_page = np.minimum(ROWS_PER_PAGE, len(keys) - starts)
+        for start in starts.tolist():
             page = self.first_page + self.n_pages
-            chunk = keys[start:start + ROWS_PER_PAGE]
-            self.backend.program_entries(page, chunk)
-            self._rows_in_page.append(len(chunk))
+            self.backend.program_entries(page,
+                                         keys[start:start + ROWS_PER_PAGE])
             self.n_pages += 1
 
     # ---------------------------------------------------------- predicates
@@ -76,44 +77,55 @@ class SimSecondaryIndex:
         Slots past a page's row count are vacant (all-ones sentinel) and
         can alias masked predicates (e.g. any column test with all-set
         bits), so the host strips them — the same software-side
-        responsibility as the header-chunk mask.  All gathers are enqueued
-        before one flush.  Raises IncompleteGatherError when a selected
-        chunk did not come back.
+        responsibility as the header-chunk mask.  A page's gather still
+        selects every chunk its header-masked bitmap hits.  The bitmaps
+        are worked as one ``(pages, 512)`` array; only the gather
+        commands are per page, all enqueued before one flush.  Raises
+        IncompleteGatherError when a gather did not return exactly the
+        chunks it selected.
         """
-        pending = []                       # (page, slots, ticket)
         with span(trace.SELECT_COLLECT):
-            for p, bitmap_words in enumerate(bitmaps):
-                page = self.first_page + p
-                bitmap = mask_header_slots(bitmap_words)
-                slots = np.nonzero(unpack_bitmap(bitmap, 512))[0]
-                slots = slots[slots < SLOTS_PER_CHUNK + self._rows_in_page[p]]
-                if slots.size == 0:
-                    continue
-                cb = int(pair_to_u64(*chunk_bitmap_from_slot_bitmap(bitmap)))
-                pending.append((page, slots, self.backend.submit_gather(
-                    Command.gather(page, cb))))
+            words = mask_header_slots(bitmaps).astype("<u4", copy=False)
+            hits = np.unpackbits(words.view(np.uint8), axis=-1,
+                                 bitorder="little").view(bool)  # (P, 512)
+            chunks = hits.reshape(-1, CHUNKS_PER_PAGE,
+                                  SLOTS_PER_CHUNK).any(axis=-1)  # (P, 64)
+            hits &= (np.arange(SLOTS_PER_PAGE)
+                     < SLOTS_PER_CHUNK + self._rows_in_page[:, None])
+            hit_pages = np.flatnonzero(hits.any(axis=-1))
+            hits, chunks = hits[hit_pages], chunks[hit_pages]
+            chunk_bitmaps = np.packbits(chunks, axis=-1,
+                                    bitorder="little").view("<u8")[:, 0]
+            pages = (self.first_page + hit_pages).tolist()
+            tickets = [self.backend.submit_gather(Command.gather(page, cb))
+                       for page, cb in zip(pages, chunk_bitmaps.tolist())]
         self.backend.flush()
 
-        rows = []
         with span(trace.SELECT_DECODE):
-            for page, slots, ticket in pending:
-                g = require_clean(ticket.result())
-                self.io_chunk_bytes += 64 * len(g.chunk_ids)
-                chunk_pos = {int(c): j for j, c in enumerate(g.chunk_ids)}
-                missing = set((slots // SLOTS_PER_CHUNK).tolist()) \
-                    - set(chunk_pos)
-                if missing:
-                    raise IncompleteGatherError(
-                        f"page {page}: chunks {sorted(missing)} not gathered")
-                out = np.zeros(slots.size, dtype=np.uint64)
-                for i, s in enumerate(slots):
-                    c, off = int(s) // SLOTS_PER_CHUNK, \
-                        (int(s) % SLOTS_PER_CHUNK) * 8
-                    out[i] = int.from_bytes(
-                        bytes(g.chunks[chunk_pos[c]][off:off + 8]), "little")
-                rows.append(out)
-        return (np.concatenate(rows) if rows
-                else np.zeros(0, dtype=np.uint64))
+            got = [require_clean(t.result()) for t in tickets]
+            n_got = np.array([g.chunk_ids.size for g in got], dtype=np.int64)
+            self.io_chunk_bytes += CHUNK_BYTES * int(n_got.sum())
+            if not got:
+                return np.zeros(0, dtype=np.uint64)
+            if not (np.array_equal(n_got, chunks.sum(axis=-1)) and
+                    np.array_equal(np.concatenate([g.chunk_ids for g in got]),
+                                   np.nonzero(chunks)[1])):
+                for page, selected, g in zip(pages, chunks, got):
+                    want = np.flatnonzero(selected)
+                    if not np.array_equal(g.chunk_ids, want):
+                        missing = sorted(set(want.tolist())
+                                         - set(g.chunk_ids.tolist()))
+                        raise IncompleteGatherError(
+                            f"page {page}: chunks {missing} not gathered"
+                            if missing else f"page {page}: chunks "
+                            f"{g.chunk_ids.tolist()} gathered, "
+                            f"{want.tolist()} selected")
+            # Position of each selected chunk among all gathered chunks.
+            pos = np.cumsum(chunks).reshape(chunks.shape) - 1
+            slot_words = np.concatenate([g.chunks for g in got]).view("<u8")
+            page_row, slot = np.nonzero(hits)
+            return slot_words[pos[page_row, slot // SLOTS_PER_CHUNK],
+                              slot % SLOTS_PER_CHUNK]
 
     def _select(self, plan: RangePlan) -> np.ndarray:
         """Rows the plan selects: one ``Op.PLAN`` per page in one flush (all

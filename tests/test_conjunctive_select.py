@@ -10,6 +10,7 @@ summed from those rows must equal the benchmark's own reference
 """
 from __future__ import annotations
 
+import dataclasses
 import glob
 import os
 import sys
@@ -24,7 +25,8 @@ from repro.backend.sharded import ShardedSsdBackend
 from repro.core.bitweaving import Column, RowCodec
 from repro.core.engine import SimChipArray
 from repro.core.range_query import conjunctive_range
-from repro.index.secondary import ROWS_PER_PAGE, SimSecondaryIndex
+from repro.index.secondary import (ROWS_PER_PAGE, IncompleteGatherError,
+                                   SimSecondaryIndex)
 from repro.kernels.sim_plan.sim_plan import MAX_PASSES
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
@@ -136,6 +138,8 @@ SELECTIONS = [
     {"shipdate": (0, 4096), "discount": (10, 11)},
     {"discount": (0, 1), "quantity": (50, 64)},
     {"shipdate": (1000, 1001), "quantity": (0, 64)},
+    # Holds the all-ones vacant slots of the partial last page.
+    {"shipdate": (2048, 4096)},
 ]
 
 
@@ -169,8 +173,43 @@ def test_one_plan_flush_and_one_gather_flush(table):
     assert (stats.flushes, stats.plans, stats.gathers) == (
         before[0] + 2, before[1] + N_PAGES, before[2] + pages)
     assert stats.gathered_chunks == chunks
+    assert index.io_chunk_bytes == 64 * stats.gathered_chunks
+    assert index.io_bitmap_bytes == 64 * N_PAGES
     # The tail copies the whole padded (rows, 64, 16) uint32 output.
     assert stats.gather_fetched_bytes == padded_rows(pages, 8) * 64 * 64
+
+
+def test_a_gather_missing_its_last_chunk_raises(index, table, monkeypatch):
+    """Every gather response loses its last chunk on the way back: the
+    selection raises ``IncompleteGatherError`` naming the first page with
+    a match and that page's last selected chunk."""
+    backend = index.backend
+    submit_gather, flush = backend.submit_gather, backend.flush
+    gathers = []
+
+    def submit(cmd):
+        gathers.append(submit_gather(cmd))
+        return gathers[-1]
+
+    def flush_and_drop():
+        flush()
+        for ticket in gathers:
+            g = ticket.result()
+            ticket._resolve(dataclasses.replace(
+                g, chunks=g.chunks[:-1], chunk_ids=g.chunk_ids[:-1],
+                parity_ok=g.parity_ok[:-1]))
+        gathers.clear()
+
+    monkeypatch.setattr(backend, "submit_gather", submit)
+    monkeypatch.setattr(backend, "flush", flush_and_drop)
+    preds = tpch.predicates(1995, 5, 24)
+    row = np.flatnonzero(numpy_where(table, preds))
+    page = row[0] // ROWS_PER_PAGE
+    # Rows start at slot 8, after the header chunk; 8 slots per chunk.
+    last = (8 + row[row // ROWS_PER_PAGE == page] % ROWS_PER_PAGE).max() // 8
+    with pytest.raises(IncompleteGatherError, match=rf"^page {page}: "
+                       rf"chunks \[{last}\] not gathered$"):
+        index.select_where(preds)
 
 
 def test_select_where_writes_its_spans(table, tmp_path):

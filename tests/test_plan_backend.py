@@ -7,6 +7,8 @@ plans; device->host result bytes drop by the plan's pass count; ticket
 resolution is lazy (launch outputs stay on-device until the first
 ``result()``) without changing any observable value.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -143,6 +145,32 @@ def test_plan_validation():
                         [MaskedQuery(query=1, mask=0x0F)])
     assert cmd2.plan_include == cmd.plan_include
     assert cmd2.plan_exclude == cmd.plan_exclude
+
+
+def test_plan_on_pages_submits_command_plan_per_page(backends,
+                                                     monkeypatch):
+    """``evaluate_plan_on_pages`` converts the passes once and shares them:
+    each page's command equals ``Command.plan(p, include, exclude)``
+    field for field, submitted in page order."""
+    bes, page_keys = backends
+    be = bes["scalar"]
+    plan = _plans(page_keys)["approx"]
+    plan = RangePlan(include=plan.include + _plans(page_keys)["exact"].include,
+                     exclude=plan.exclude, exact=False)
+    assert len(plan.include) > 1 and plan.exclude
+    submitted = []
+    submit_plan = be.submit_plan
+
+    def submit(cmd):
+        submitted.append(cmd)
+        return submit_plan(cmd)
+
+    monkeypatch.setattr(be, "submit_plan", submit)
+    pages = [5, 0, 11, 3]
+    evaluate_plan_on_pages(be, plan, pages)
+    want = [Command.plan(p, plan.include, plan.exclude) for p in pages]
+    assert [dataclasses.asdict(c) for c in submitted] == \
+        [dataclasses.asdict(c) for c in want]
 
 
 # ------------------------------------------------------------- lazy tickets
