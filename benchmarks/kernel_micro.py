@@ -25,7 +25,7 @@ from repro.frontend import RunConfig, replay
 from repro.workload.ycsb import generate
 
 
-def _programmed_backend(name: str, n_pages: int, seed: int = 5):
+def _programmed_backend(n_pages: int, seed: int = 5):
     arr = SimChipArray(n_chips=8, pages_per_chip=max(n_pages // 8 + 1, 8),
                        device_seed=seed)
     rng = np.random.default_rng(0)
@@ -33,68 +33,21 @@ def _programmed_backend(name: str, n_pages: int, seed: int = 5):
                  for _ in range(n_pages)]
     for p, keys in enumerate(page_keys):
         arr.program_entries(p, keys)
-    return make_backend(name, arr), page_keys
-
-
-def backend_batch_comparison(n_pages: int = 32,
-                             batch_sizes=(4, 16, 64)) -> None:
-    """Scalar per-page path vs one-launch batched backend (§IV-E).
-
-    Workload: Q concurrent point queries, each matched against all
-    ``n_pages`` staged pages (the cross-page multi-query batch an index
-    burst produces).  The scalar backend walks SimChip.search per
-    (query, page); the batched backend stages everything and launches the
-    sim_search kernel once.  Emitted derived column carries the speedup —
-    the repo's regression gate wants >= 2x at Q >= 16.
-    """
-    for n_q in batch_sizes:
-        scalar, page_keys = _programmed_backend("scalar", n_pages)
-        batched, _ = _programmed_backend("batched", n_pages)
-        rng = np.random.default_rng(1)
-        queries = [int(page_keys[p][rng.integers(0, 404)])
-                   for p in rng.integers(0, n_pages, n_q)]
-        cmds = [Command.search(p, q)
-                for q in queries for p in range(n_pages)]
-
-        def burst(backend):
-            tickets = [backend.submit_search(c) for c in cmds]
-            backend.flush()
-            return [t.result().match_count for t in tickets]
-
-        counts_b = burst(batched)               # warm compile
-        with Timer() as tb:
-            burst(batched)
-        counts_s = burst(scalar)
-        with Timer() as ts:
-            burst(scalar)
-        assert counts_s == counts_b, "backend results diverged"
-        speedup = ts.elapsed_us / tb.elapsed_us
-        # Regression gate: batching must pay off once a burst is real.
-        # (2x is far below the ~10x this container shows; headroom covers
-        # interpret-mode timing noise.)
-        assert n_q < 16 or speedup >= 2.0, \
-            f"batched backend speedup {speedup:.1f}x < 2x at q={n_q}"
-        n = len(cmds)
-        emit("backend_scalar_search", ts.elapsed_us / n,
-             f"q={n_q}_pages={n_pages}_searches={n}")
-        emit("backend_batched_search", tb.elapsed_us / n,
-             f"q={n_q}_pages={n_pages}_one_launch_speedup={speedup:.1f}x")
+    return make_backend("sharded", arr), page_keys
 
 
 def functional_burst_comparison(n_queries: int = 384,
                                 n_key_pages: int = 8) -> None:
-    """End-to-end functional replay: scalar vs batched-split vs fused.
+    """End-to-end functional replay: scalar vs the fused lookup path.
 
-    The read-heavy YCSB stream is replayed three ways: per-command scalar
-    chips, the batched backend's split path (search launch -> host bitmap
-    decode -> gather launch, 2 launches/burst) and the fused lookup path
-    (1 launch/burst, match->slot-select-value-gather in-kernel).  Page
-    programming is identical setup for all three paths; a 1-query run per
-    path measures it and its time is subtracted, so the emitted per-query
-    numbers and the regression gate reflect burst execution only.  The gate
-    mirrors the search section's: the fused path must beat the scalar
-    reference by >= 2x (it shows more; headroom covers interpret-mode
-    noise).  Values must be bit-identical across all three.
+    The read-heavy YCSB stream is replayed two ways: per-command scalar
+    chips and the sharded backend's fused lookup path (1 launch/burst,
+    match->slot-select-value-gather in-kernel).  Page programming is
+    identical setup for both paths; a 1-query run per path measures it and
+    its time is subtracted, so the emitted per-query numbers and the
+    regression gate reflect burst execution only.  The gate: the fused
+    path must beat the scalar reference by >= 2x (it shows more; headroom
+    covers interpret-mode noise).  Values must be bit-identical.
     """
     wl = generate(n_queries, n_key_pages=n_key_pages, read_ratio=1.0,
                   alpha=0.5, seed=9)
@@ -110,8 +63,7 @@ def functional_burst_comparison(n_queries: int = 384,
 
     results, times = {}, {}
     for label, name, fused in (("scalar", "scalar", False),
-                               ("batched", "batched", False),
-                               ("fused", "batched", True)):
+                               ("fused", "sharded", True)):
         once(name, fused)                       # warm compile caches
         once(name, fused, wl_tiny)              # ... incl. tiny-burst shapes
         with Timer() as t0:
@@ -125,14 +77,11 @@ def functional_burst_comparison(n_queries: int = 384,
                                       r.read_values)
     assert results["fused"].kernel_launches == results["fused"].flushes, \
         "fused read burst must be exactly one launch per flush"
-    speed_b = times["scalar"] / times["batched"]
     speed_f = times["scalar"] / times["fused"]
     assert speed_f >= 2.0, \
         f"fused replay speedup {speed_f:.1f}x < 2x gate"
     emit("functional_scalar", times["scalar"] / n_queries,
          f"q={n_queries}_per_command_reference")
-    emit("functional_batched", times["batched"] / n_queries,
-         f"q={n_queries}_2_launches_per_burst_speedup={speed_b:.1f}x")
     emit("functional_fused", times["fused"] / n_queries,
          f"q={n_queries}_1_launch_per_burst_speedup={speed_f:.1f}x")
 
@@ -142,7 +91,7 @@ def write_path_comparison(n_queries: int = 384,
     """Coalescing DRAM write buffer vs per-write reprogram (§VI write path).
 
     The same write-heavy YCSB-A stream (read_ratio=0.5, alpha=0.9) replays
-    twice on the batched backend: unbuffered, every write force-splits the
+    twice on the sharded backend: unbuffered, every write force-splits the
     open read burst and synchronously reprograms its value page (1 program
     + 1 dirty-row restage per write, zero coalescing); buffered, writes
     absorb into the DRAM write buffer (reads of dirty pages served from
@@ -163,7 +112,7 @@ def write_path_comparison(n_queries: int = 384,
     def once(buffered: bool, workload=wl):
         arr = SimChipArray(n_chips=4, pages_per_chip=pages_per_chip,
                            device_seed=3)
-        return replay(workload, make_backend("batched", arr),
+        return replay(workload, make_backend("sharded", arr),
                       RunConfig(burst=64, fused=True, write_buffer=buffered,
                                 write_high_water=8))
 
@@ -216,7 +165,7 @@ def staged_bytes_per_flush(n_pages: int = 32, n_q: int = 16) -> None:
     ZERO page bytes — only the (Q, 2) query operands.  A reprogram
     invalidates exactly one arena row (one page restage).
     """
-    backend, page_keys = _programmed_backend("batched", n_pages)
+    backend, page_keys = _programmed_backend(n_pages)
     rng = np.random.default_rng(2)
     cmds = [Command.search(p, int(page_keys[p][rng.integers(0, 404)]))
             for p in range(n_pages) for _ in range(n_q // 4)]
@@ -250,14 +199,15 @@ def range_plan_comparison(n_pages: int = 32) -> None:
     """Fused Op.PLAN vs per-pass searches (Fig 10 in-latch accumulation).
 
     An exact 64-bit range decomposes into ~100 masked-equality passes; the
-    per-pass path launches them as one batched search (Q = passes) and
+    per-pass path launches them as one stacked search (Q = passes) and
     combines passes x pages bitmaps on the host, crossing 64 B per pass
     per page.  The fused PLAN path evaluates and combines every pass
-    in-VMEM and ships ONE 64 B bitmap per page.  Gates: the result-byte
-    counters are exact contracts (the drop == the plan's pass count), and
-    the fused path must beat the per-pass batched path >= 2x end to end
-    (``plan_fused_speedup``, also floored in check_regression.py).
-    Scalar / batched / sharded results are asserted bit-identical.
+    in-VMEM and ships ONE 64 B bitmap per page.  Both run on a 4x2
+    sharded backend.  Gates: the result-byte counters are exact contracts
+    (the drop == the plan's pass count), and the fused path must beat the
+    per-pass path >= 2x end to end (``plan_fused_speedup``, also floored
+    in check_regression.py).  Scalar and sharded results are asserted
+    bit-identical.
     """
     rng = np.random.default_rng(7)
     page_keys = [rng.integers(1, 2**62, 404, dtype=np.uint64)
@@ -270,48 +220,40 @@ def range_plan_comparison(n_pages: int = 32) -> None:
     assert plan.n_passes > 90, plan.n_passes
     pages = list(range(n_pages))
 
-    def programmed(name):
-        if name == "sharded":
-            be = ShardedSsdBackend.from_geometry(
-                channels=4, dies_per_channel=2,
-                pages_per_chip=n_pages // 8 + 1, device_seed=5)
-        else:
-            be = make_backend(name, SimChipArray(
-                n_chips=8, pages_per_chip=n_pages // 8 + 1, device_seed=5))
+    def programmed(be):
         for p, keys in enumerate(page_keys):
             be.program_entries(p, keys)
         return be
 
-    scalar = programmed("scalar")
-    batched = programmed("batched")
-    sharded = programmed("sharded")
+    scalar = programmed(make_backend("scalar", SimChipArray(
+        n_chips=8, pages_per_chip=n_pages // 8 + 1, device_seed=5)))
+    sharded = programmed(ShardedSsdBackend.from_geometry(
+        channels=4, dies_per_channel=2, pages_per_chip=n_pages // 8 + 1,
+        device_seed=5))
 
     # Warm arenas + compile caches, and check cross-backend bit-parity.
     ref = evaluate_plan_on_pages(scalar, plan, pages)
-    per_pass_ref = evaluate_plan_per_pass(batched, plan, pages)
-    np.testing.assert_array_equal(ref, per_pass_ref)
-    for be in (batched, sharded):
-        np.testing.assert_array_equal(ref, evaluate_plan_on_pages(
-            be, plan, pages))
+    np.testing.assert_array_equal(ref, evaluate_plan_per_pass(
+        sharded, plan, pages))
+    np.testing.assert_array_equal(ref, evaluate_plan_on_pages(
+        sharded, plan, pages))
 
-    rb0 = batched.stats.result_bytes
+    rb0 = sharded.stats.result_bytes
     with Timer() as tpp:
-        evaluate_plan_per_pass(batched, plan, pages)
-    per_pass_bytes = batched.stats.result_bytes - rb0
-    rb0 = batched.stats.result_bytes
+        evaluate_plan_per_pass(sharded, plan, pages)
+    per_pass_bytes = sharded.stats.result_bytes - rb0
+    rb0 = sharded.stats.result_bytes
     with Timer() as tf:
-        evaluate_plan_on_pages(batched, plan, pages)
-    fused_bytes = batched.stats.result_bytes - rb0
+        evaluate_plan_on_pages(sharded, plan, pages)
+    fused_bytes = sharded.stats.result_bytes - rb0
     # Best-of-2 on both timed paths: interpret-mode wall noise must not
     # flap the ratio gate.
     with Timer() as tpp2:
-        evaluate_plan_per_pass(batched, plan, pages)
+        evaluate_plan_per_pass(sharded, plan, pages)
     with Timer() as tf2:
-        evaluate_plan_on_pages(batched, plan, pages)
+        evaluate_plan_on_pages(sharded, plan, pages)
     t_pp = min(tpp.elapsed_us, tpp2.elapsed_us)
     t_f = min(tf.elapsed_us, tf2.elapsed_us)
-    with Timer() as tsh:
-        evaluate_plan_on_pages(sharded, plan, pages)
     with Timer() as tsc:
         evaluate_plan_on_pages(scalar, plan, pages)
 
@@ -322,11 +264,9 @@ def range_plan_comparison(n_pages: int = 32) -> None:
     assert speedup >= 2.0, \
         f"fused plan speedup {speedup:.1f}x < 2x gate"
     emit("range_plan_per_pass", t_pp / n_pages,
-         f"passes={plan.n_passes}_pages={n_pages}_batched_search_combine")
+         f"passes={plan.n_passes}_pages={n_pages}_stacked_search_combine")
     emit("range_plan_fused", t_f / n_pages,
          f"passes={plan.n_passes}_pages={n_pages}_one_plan_launch")
-    emit("range_plan_fused_sharded", tsh.elapsed_us / n_pages,
-         f"passes={plan.n_passes}_pages={n_pages}_geometry=4x2")
     emit("range_plan_scalar", tsc.elapsed_us / n_pages,
          f"passes={plan.n_passes}_pages={n_pages}_per_pass_chip_reference")
     emit("plan_fused_speedup", speedup,
@@ -507,7 +447,6 @@ def main(scale: int = 1) -> None:
     emit("kernel_flash_attention", t.elapsed_us,
          f"causal_gqa_flops={flops}")
 
-    backend_batch_comparison()
     functional_burst_comparison()
     write_path_comparison()
     staged_bytes_per_flush()

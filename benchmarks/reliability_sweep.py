@@ -13,7 +13,7 @@ every fresh run, baseline or not):
   seed; the gate is (a) zero wrong results against the analytic oracle —
   every read either returns the exact stored value or a typed
   ``UncorrectableReadError``, never a silently wrong/missing one — and
-  (b) bit-identical per-op outcomes across scalar/batched/sharded.
+  (b) bit-identical per-op outcomes across scalar/sharded.
 
 * **Unverified sweep** — clean storage, transient comparator noise only
   (``sense_ber=5e-4``), verification and miss-fallback disabled.  This is
@@ -48,7 +48,7 @@ FAULT_SEED = 11
 BASE_BER = 1e-4
 SENSE_BER = 5e-4
 AGES_DAYS = (0, 45, 90)
-BACKENDS = ("scalar", "batched", "sharded")
+BACKENDS = ("scalar", "sharded")
 
 
 def _workload():

@@ -2,8 +2,8 @@
 
 Default run executes all three prongs — the AST contract lint
 (SIM001..SIM009) over ``src/repro`` and ``benchmarks/``, the trace-time
-launch audit (SIM101..SIM105) over the batched and sharded backends, and
-the runtime conservation audit (SIM201..SIM204) of the timeline
+launch audit (SIM101..SIM105) over the sharded backend on one chip and on
+four, and the runtime conservation audit (SIM201..SIM204) of the timeline
 accounting — applies ``baseline.toml`` and prints every finding.
 ``--check`` turns non-baselined findings into a nonzero exit (the CI
 gate); ``--write-baseline`` regenerates the allowlist from the current
@@ -63,10 +63,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="skip the audit's compiled-HLO byte cross-check")
     p.add_argument("--no-conservation", action="store_true",
                    help="skip the runtime conservation audit (SIM201..204)")
-    p.add_argument("--backends", nargs="*", default=("batched", "sharded"),
-                   choices=("batched", "sharded"),
-                   help="backend kinds the launch and conservation audits "
-                        "drive")
     return p
 
 
@@ -96,17 +92,16 @@ def collect_findings(args) -> list[Finding]:
         findings.extend(run_contracts(args.root, paths=paths, rules=rules))
     if not args.no_audit:
         from .launch_audit import run_audit
-        findings.extend(run_audit(kinds=tuple(args.backends),
-                                  hlo=not args.no_hlo))
+        findings.extend(run_audit(hlo=not args.no_hlo))
     if not args.no_conservation:
         from .conservation import run_conservation
-        findings.extend(run_conservation(kinds=tuple(args.backends)))
+        findings.extend(run_conservation())
     return findings
 
 
 def _github_annotation(f: Finding) -> str:
     """One ::error problem-matcher line per new finding.  Audit findings
-    (path ``audit:<kind>``) have no source location; they annotate the
+    (path ``audit:sharded``) have no source location; they annotate the
     workflow without file/line coordinates."""
     msg = f"{f.rule} [{f.slug}] {f.symbol}: {f.message or f.slug}"
     msg = msg.replace("%", "%25").replace("\r", "%0D").replace("\n", "%0A")

@@ -2,10 +2,11 @@
 
 The static prongs (AST rules, jaxpr launch audit) prove the *shape* of
 the accounting is right; this prong proves the books actually balance at
-runtime.  It replays a small seeded YCSB slice per backend through the
-real frontend with a metering ``BurstTimeline`` subclass that records
-every resource-line occupancy interval ``SSDSim`` grants, then audits —
-the timeline-layer sibling of SIM104's jaxpr byte reconciliation:
+runtime.  It replays a small seeded YCSB slice on the sharded backend
+through the real frontend with a metering ``BurstTimeline`` subclass
+that records every resource-line occupancy interval ``SSDSim`` grants,
+then audits — the timeline-layer sibling of SIM104's jaxpr byte
+reconciliation:
 
   * **SIM201 (busy-time conservation)** — every resource line (each
     die's sense and program timelines, each channel bus, the PCIe link)
@@ -29,7 +30,7 @@ the timeline-layer sibling of SIM104's jaxpr byte reconciliation:
     popcount, a healthy schedule fires nothing, and a dead chip with
     replicas surfaces as failovers/degraded reads with zero op errors.
 
-Findings carry path ``audit:<kind>`` and flow through the same
+Findings carry path ``audit:sharded`` and flow through the same
 ``(rule, path, symbol, slug)`` baseline diff as every other prong.
 """
 from __future__ import annotations
@@ -192,49 +193,42 @@ def energy_violations(energy, params, *, n_senses: int, n_programs: int,
 class _Auditor:
     """Finding collector in the launch_audit idiom."""
 
-    def __init__(self, kind: str):
-        self.kind = kind
+    def __init__(self):
         self.findings: list[Finding] = []
 
     def check(self, ok: bool, rule: str, symbol: str, slug: str,
               message: str) -> None:
         if not ok:
             self.findings.append(Finding(
-                rule, f"audit:{self.kind}", symbol, slug, message=message))
+                rule, "audit:sharded", symbol, slug, message=message))
 
     def add(self, rule: str, symbol: str,
             violations: list[tuple[str, str]]) -> None:
         for slug, message in violations:
             self.findings.append(Finding(
-                rule, f"audit:{self.kind}", symbol, slug, message=message))
+                rule, "audit:sharded", symbol, slug, message=message))
 
 
-def _audit_kind(kind: str) -> list[Finding]:
+def run_conservation() -> list[Finding]:
+    """Run the seeded conservation replays; returns all findings."""
     import numpy as np
 
-    from repro.backend.base import make_backend
     from repro.backend.sharded import ShardedSsdBackend
     from repro.core.engine import SimChipArray
     from repro.frontend import RunConfig, replay
     from repro.reliability import FaultSchedule
     from repro.workload.ycsb import generate
 
-    aud = _Auditor(kind)
+    aud = _Auditor()
     wl = generate(120, n_key_pages=4, read_ratio=0.7, alpha=0.5, seed=2)
-    if kind == "sharded":
-        tl = make_metered_timeline(n_chips=4)
-        backend = ShardedSsdBackend(
-            SimChipArray(n_chips=4, pages_per_chip=64, device_seed=11),
-            page_block=8, lookup_block=8, use_kernel=False, interpret=True,
-            timeline=tl)
-    else:
-        tl = None
-        backend = make_backend(kind, SimChipArray(
-            n_chips=4, pages_per_chip=64, device_seed=11),
-            page_block=8, lookup_block=8, use_kernel=False)
+    tl = make_metered_timeline(n_chips=4)
+    backend = ShardedSsdBackend(
+        SimChipArray(n_chips=4, pages_per_chip=64, device_seed=11),
+        page_block=8, lookup_block=8, use_kernel=False, interpret=True,
+        timeline=tl)
     rep = replay(wl, backend, RunConfig(burst=16))
 
-    # --- SIM203: bytes reconcile backend <-> report (every kind)
+    # --- SIM203: bytes reconcile backend <-> report
     stats = backend.stats
     for field in ("staged_bytes", "result_bytes", "kernel_launches"):
         aud.check(getattr(rep.counters, field) == getattr(stats, field),
@@ -246,83 +240,73 @@ def _audit_kind(kind: str) -> list[Finding]:
               "no-result-bytes",
               "a 120-op read-heavy replay produced zero result bytes")
 
-    if tl is not None:
-        # --- SIM201: per-line busy time vs makespan
-        makespan_ns = max([tl.now] + [e.end_ns for e in tl.events])
-        aud.add("SIM201", "timeline", busy_violations(tl.events,
-                                                      makespan_ns))
-        aud.check(rep.latency.makespan_ns == tl.now, "SIM201", "timeline",
-                  "makespan-mismatch",
-                  f"report makespan {rep.latency.makespan_ns} != timeline "
-                  f"clock {tl.now}")
-        # --- SIM202: energy account vs metered recomputation
-        senses = sum(e.line.startswith("die_sense:") for e in tl.events)
-        programs = sum(e.line.startswith("die_prog:") for e in tl.events)
-        bus_events = [(e.n_bytes, e.match_mode) for e in tl.events
-                      if e.line.startswith("chan:")]
-        aud.add("SIM202", "timeline", energy_violations(
-            tl.sim.energy, tl.params, n_senses=senses,
-            n_programs=programs, bus_events=bus_events,
-            match_queries=tl.match_queries))
-        aud.check(rep.energy.total_pj == tl.sim.energy.total_pj,
-                  "SIM202", "timeline", "report-mismatch:energy_pj",
-                  f"report energy {rep.energy.total_pj} != timeline "
-                  f"account {tl.sim.energy.total_pj}")
-        # --- SIM203 (cross-layer leg): counters vs metered bytes
-        aud.check(tl.sim.stats.internal_bytes
-                  == sum(n for n, _ in bus_events),
-                  "SIM203", "timeline", "bus-bytes-mismatch",
-                  f"sim internal_bytes {tl.sim.stats.internal_bytes} != "
-                  f"metered bus payload {sum(n for n, _ in bus_events)}")
-        pcie = sum(e.n_bytes for e in tl.events if e.line == "pcie")
-        aud.check(tl.sim.stats.pcie_bytes == pcie,
-                  "SIM203", "timeline", "pcie-bytes-mismatch",
-                  f"sim pcie_bytes {tl.sim.stats.pcie_bytes} != metered "
-                  f"PCIe payload {pcie}")
+    # --- SIM201: per-line busy time vs makespan
+    makespan_ns = max([tl.now] + [e.end_ns for e in tl.events])
+    aud.add("SIM201", "timeline", busy_violations(tl.events,
+                                                  makespan_ns))
+    aud.check(rep.latency.makespan_ns == tl.now, "SIM201", "timeline",
+              "makespan-mismatch",
+              f"report makespan {rep.latency.makespan_ns} != timeline "
+              f"clock {tl.now}")
+    # --- SIM202: energy account vs metered recomputation
+    senses = sum(e.line.startswith("die_sense:") for e in tl.events)
+    programs = sum(e.line.startswith("die_prog:") for e in tl.events)
+    bus_events = [(e.n_bytes, e.match_mode) for e in tl.events
+                  if e.line.startswith("chan:")]
+    aud.add("SIM202", "timeline", energy_violations(
+        tl.sim.energy, tl.params, n_senses=senses,
+        n_programs=programs, bus_events=bus_events,
+        match_queries=tl.match_queries))
+    aud.check(rep.energy.total_pj == tl.sim.energy.total_pj,
+              "SIM202", "timeline", "report-mismatch:energy_pj",
+              f"report energy {rep.energy.total_pj} != timeline "
+              f"account {tl.sim.energy.total_pj}")
+    # --- SIM203 (cross-layer leg): counters vs metered bytes
+    aud.check(tl.sim.stats.internal_bytes
+              == sum(n for n, _ in bus_events),
+              "SIM203", "timeline", "bus-bytes-mismatch",
+              f"sim internal_bytes {tl.sim.stats.internal_bytes} != "
+              f"metered bus payload {sum(n for n, _ in bus_events)}")
+    pcie = sum(e.n_bytes for e in tl.events if e.line == "pcie")
+    aud.check(tl.sim.stats.pcie_bytes == pcie,
+              "SIM203", "timeline", "pcie-bytes-mismatch",
+              f"sim pcie_bytes {tl.sim.stats.pcie_bytes} != metered "
+              f"PCIe payload {pcie}")
 
-    # --- SIM204: fault accounting (sharded only: the fault tier's home)
-    if kind == "sharded":
-        def replicated(replicas, faults):
-            per_chip = (wl.n_index_pages // 4 + 1) * (replicas + 1)
-            be = ShardedSsdBackend(
-                SimChipArray(n_chips=4, pages_per_chip=per_chip,
-                             device_seed=3),
-                use_kernel=False, interpret=True, replicas=replicas)
-            return replay(wl, be, RunConfig.event_serial(
-                faults=faults, burst=16, seed=7))
+    # --- SIM204: fault accounting
+    def replicated(replicas, faults):
+        per_chip = (wl.n_index_pages // 4 + 1) * (replicas + 1)
+        be = ShardedSsdBackend(
+            SimChipArray(n_chips=4, pages_per_chip=per_chip,
+                         device_seed=3),
+            use_kernel=False, interpret=True, replicas=replicas)
+        return replay(wl, be, RunConfig.event_serial(
+            faults=faults, burst=16, seed=7))
 
-        healthy = replicated(2, FaultSchedule.healthy(seed=7))
-        f = healthy.faults
-        aud.check((f.timeouts, f.retries, f.failovers, f.degraded_ops,
-                   f.n_op_errors) == (0, 0, 0, 0, 0),
-                  "SIM204", "faults", "healthy-run-fired",
-                  "a healthy fault schedule produced nonzero fault "
-                  "counters")
-        dead = replicated(2, FaultSchedule.dead_chip(chip=0, seed=7))
-        f = dead.faults
-        aud.check(f.op_errors is not None
-                  and len(f.op_errors) == len(wl.ops),
-                  "SIM204", "faults", "mask-shape",
-                  "op_errors mask does not cover every op")
-        aud.check(f.op_errors is not None
-                  and f.n_op_errors == int(np.sum(f.op_errors)),
-                  "SIM204", "faults", "mask-count-mismatch",
-                  f"n_op_errors={f.n_op_errors} != popcount of the "
-                  "op_errors mask")
-        aud.check(f.failovers > 0 and f.degraded_ops > 0,
-                  "SIM204", "faults", "dead-chip-invisible",
-                  "a dead chip with replicas produced no failovers or "
-                  "degraded reads — the fault path did not run")
-        aud.check(f.n_op_errors == 0, "SIM204", "faults",
-                  "replicated-errors",
-                  "replicas=2 should absorb a single dead chip with zero "
-                  "op errors")
+    healthy = replicated(2, FaultSchedule.healthy(seed=7))
+    f = healthy.faults
+    aud.check((f.timeouts, f.retries, f.failovers, f.degraded_ops,
+               f.n_op_errors) == (0, 0, 0, 0, 0),
+              "SIM204", "faults", "healthy-run-fired",
+              "a healthy fault schedule produced nonzero fault "
+              "counters")
+    dead = replicated(2, FaultSchedule.dead_chip(chip=0, seed=7))
+    f = dead.faults
+    aud.check(f.op_errors is not None
+              and len(f.op_errors) == len(wl.ops),
+              "SIM204", "faults", "mask-shape",
+              "op_errors mask does not cover every op")
+    aud.check(f.op_errors is not None
+              and f.n_op_errors == int(np.sum(f.op_errors)),
+              "SIM204", "faults", "mask-count-mismatch",
+              f"n_op_errors={f.n_op_errors} != popcount of the "
+              "op_errors mask")
+    aud.check(f.failovers > 0 and f.degraded_ops > 0,
+              "SIM204", "faults", "dead-chip-invisible",
+              "a dead chip with replicas produced no failovers or "
+              "degraded reads — the fault path did not run")
+    aud.check(f.n_op_errors == 0, "SIM204", "faults",
+              "replicated-errors",
+              "replicas=2 should absorb a single dead chip with zero "
+              "op errors")
     return aud.findings
-
-
-def run_conservation(kinds=("batched", "sharded")) -> list[Finding]:
-    """Run the seeded conservation replays; returns all findings."""
-    out: list[Finding] = []
-    for kind in kinds:
-        out.extend(_audit_kind(kind))
-    return out

@@ -8,11 +8,11 @@ transfers), stable retrace signatures across burst sizes (the pow2 padding
 bounds distinct abstract signatures to O(log max_burst)), and byte
 counters that reconcile against what the traced program actually moves.
 
-The auditor enforces this dynamically: it wraps each backend's device
-entry points (``sim_search``/``sim_plan``/``sim_fused_lookup``/
-``sim_gather`` on batched, the ``_stacked_*`` jits on sharded) with a
-recorder that re-traces every call via ``jax.make_jaxpr`` and summarizes
-the jaxpr, then drives a scripted scenario through every flush path —
+The auditor enforces this dynamically: it wraps the sharded backend's
+device entry points (the ``_stacked_search``/``_stacked_plan`` jits,
+``sim_fused_lookup`` and ``sim_gather``) with a recorder that re-traces
+every call via ``jax.make_jaxpr`` and summarizes the jaxpr, then drives a
+scripted scenario, on one chip or on four, through every flush path —
 search (cold + warm), plan, lookup, gather, and the zero-launch
 program-group — checking after each phase:
 
@@ -33,14 +33,13 @@ program-group — checking after each phase:
     from ``lower().compiler_ir('hlo')`` text (via launch/hlo_analysis)
     match the jaxpr operand/result bytes.
 
-Failures surface as :class:`Finding` rows (path ``audit:<backend>``) that
+Failures surface as :class:`Finding` rows (path ``audit:sharded``) that
 flow through the same baseline/check gate as the AST lint.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import importlib
 import math
 from typing import Callable, Iterator
 
@@ -50,8 +49,9 @@ from repro.core.bits import PAGE_BYTES
 from repro.core.commands import Command
 from repro.core.engine import SimChipArray
 from repro.core.range_query import exact_range
-from repro.backend.base import MatchBackend, make_backend
 from repro.backend.planestore import next_pow2, padded_rows
+from repro.backend import sharded
+from repro.backend.sharded import ShardedSsdBackend
 from repro.launch.hlo_analysis import _shape_bytes, parse_computations
 
 from .findings import Finding
@@ -61,13 +61,13 @@ FORBIDDEN_PRIMITIVES = frozenset({
     "device_put", "infeed", "outfeed",
 })
 
-_PATCH_POINTS = {
-    "batched": ("repro.backend.batched",
-                ("sim_search", "sim_plan", "sim_fused_lookup", "sim_gather")),
-    "sharded": ("repro.backend.sharded",
-                ("_stacked_search", "_stacked_plan", "sim_fused_lookup",
-                 "sim_gather")),
-}
+# The sharded backend's device entry points, looked up as module globals
+# at call time.
+_ENTRY_POINTS = ("_stacked_search", "_stacked_plan", "sim_fused_lookup",
+                 "sim_gather")
+
+#: chip counts the audit drives: a one-chip SSD and a four-chip one
+CHIP_COUNTS = (1, 4)
 
 
 # --------------------------------------------------------------- jaxpr walk
@@ -183,19 +183,18 @@ def _record_wrapper(orig, entry_name: str, records: list):
 
 
 @contextlib.contextmanager
-def record_launches(kind: str):
-    """Patch ``kind``'s device entry points; yields the record list."""
-    modname, names = _PATCH_POINTS[kind]
-    mod = importlib.import_module(modname)
+def record_launches():
+    """Patch the sharded backend's device entry points; yields the record
+    list."""
     records: list[LaunchRecord] = []
-    saved = {n: getattr(mod, n) for n in names}
+    saved = {n: getattr(sharded, n) for n in _ENTRY_POINTS}
     try:
         for n, f in saved.items():
-            setattr(mod, n, _record_wrapper(f, n, records))
+            setattr(sharded, n, _record_wrapper(f, n, records))
         yield records
     finally:
         for n, f in saved.items():
-            setattr(mod, n, f)
+            setattr(sharded, n, f)
 
 
 # ------------------------------------------------------------ HLO cross-check
@@ -233,17 +232,15 @@ N_ENTRIES = 12
 
 
 class _Auditor:
-    def __init__(self, kind: str, *, use_kernel: bool = True,
+    def __init__(self, n_chips: int, *, use_kernel: bool = True,
                  hlo: bool = True):
-        self.kind = kind
+        self.n_chips = n_chips
         self.hlo = hlo
         self.findings: list[Finding] = []
-        n_chips = 4 if kind == "sharded" else 2
         self.chips = SimChipArray(n_chips=n_chips, pages_per_chip=64,
                                   device_seed=11)
-        self.backend: MatchBackend = make_backend(
-            kind, self.chips, page_block=8, lookup_block=8,
-            use_kernel=use_kernel)
+        self.backend = ShardedSsdBackend(
+            self.chips, page_block=8, lookup_block=8, use_kernel=use_kernel)
         for p in range(N_KEY_PAGES):
             self.backend.program_entries(
                 p, [_key(p, i) for i in range(N_ENTRIES)])
@@ -255,7 +252,7 @@ class _Auditor:
               msg: str) -> None:
         if not cond:
             self.findings.append(Finding(
-                rule, f"audit:{self.kind}", symbol, slug, message=msg))
+                rule, "audit:sharded", symbol, slug, message=msg))
 
     # ------------------------------------------------------------ one phase
     def run_phase(self, records: list, phase: str, submit, *,
@@ -344,20 +341,14 @@ class _Auditor:
 
     # ------------------------------------------------------------ scenario
     def expected_search_rows(self, addr_lists: list[list[int]]) -> int:
-        """Padded plane rows for per-chip unique page lists (sharded) or a
-        single flat list (batched)."""
+        """Padded plane rows for per-chip unique page lists."""
         block = self.backend.page_block
-        if self.kind == "batched":
-            (addrs,) = addr_lists
-            return padded_rows(len(addrs), block)
         n_pad = max(padded_rows(len(a), block) for a in addr_lists if a)
         c_pad = next_pow2(sum(1 for a in addr_lists if a))
         return c_pad * n_pad
 
     def per_chip(self, addrs: list[int]) -> list[list[int]]:
-        if self.kind == "batched":
-            return [sorted(set(addrs), key=addrs.index)]
-        n = len(self.chips.chips)
+        n = self.n_chips
         out: list[list[int]] = [[] for _ in range(n)]
         for a in addrs:
             if a not in out[a % n]:
@@ -365,7 +356,7 @@ class _Auditor:
         return out
 
     def run(self) -> list[Finding]:
-        with record_launches(self.kind) as records:
+        with record_launches() as records:
             self._scenario(records)
         self._retrace_sweep()
         return self.findings
@@ -468,14 +459,13 @@ class _Auditor:
     def _retrace_sweep(self, burst_sizes=(1, 2, 3, 4, 5, 6, 8, 12, 16)):
         """Distinct abstract signatures across a burst sweep must stay
         within the pow2-padding bound: O(log max_burst), not O(bursts)."""
-        chips = SimChipArray(n_chips=4 if self.kind == "sharded" else 2,
-                             pages_per_chip=64, device_seed=11)
-        backend = make_backend(self.kind, chips, page_block=8,
-                               lookup_block=8, use_kernel=True)
+        chips = SimChipArray(n_chips=self.n_chips, pages_per_chip=64,
+                             device_seed=11)
+        backend = ShardedSsdBackend(chips, page_block=8, lookup_block=8,
+                                    use_kernel=True)
         for p in range(4):
             backend.program_entries(p, [_key(p, i) for i in range(32)])
-        entry_names = ("sim_search", "_stacked_search")
-        with record_launches(self.kind) as records:
+        with record_launches() as records:
             q = 0
             for size in burst_sizes:
                 tickets = []
@@ -487,7 +477,7 @@ class _Auditor:
                 for t in tickets:
                     t.result()
         sigs = {r.summary.signature for r in records
-                if r.entry in entry_names}
+                if r.entry == "_stacked_search"}
         bound = int(math.log2(next_pow2(max(burst_sizes)))) + 1
         self.check(0 < len(sigs) <= bound, "SIM103", "retrace-sweep",
                    "distinct-signatures",
@@ -506,15 +496,14 @@ class _Auditor:
                        f"(bound {bound})")
 
 
-def audit_backend(kind: str, *, use_kernel: bool = True,
+def audit_backend(n_chips: int = 4, *, use_kernel: bool = True,
                   hlo: bool = True) -> list[Finding]:
-    """Run the full launch audit for one backend kind."""
-    return _Auditor(kind, use_kernel=use_kernel, hlo=hlo).run()
+    """Run the full launch audit on an ``n_chips`` sharded backend."""
+    return _Auditor(n_chips, use_kernel=use_kernel, hlo=hlo).run()
 
 
-def run_audit(kinds=("batched", "sharded"), *, hlo: bool = True
-              ) -> list[Finding]:
+def run_audit(*, hlo: bool = True) -> list[Finding]:
     findings: list[Finding] = []
-    for kind in kinds:
-        findings.extend(audit_backend(kind, hlo=hlo))
+    for n_chips in CHIP_COUNTS:
+        findings.extend(audit_backend(n_chips, hlo=hlo))
     return findings

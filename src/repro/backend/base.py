@@ -7,17 +7,19 @@ backend and flush, which is what turns a B+Tree range scan or a YCSB read
 burst into one device operation instead of a per-page command storm
 (paper §IV-E batch matching).
 
-Two interchangeable implementations ship today:
+Two implementations ship today:
 
   * ``ScalarBackend`` (scalar.py) — the numpy ``SimChip``/``SimChipArray``
     functional model, executing queued commands one page at a time.  This is
     the bit-exact reference, with the full latch/ECC machinery.
-  * ``BatchedKernelBackend`` (batched.py) — keeps stored pages *device
-    resident* in a ``PlaneStore`` arena (planestore.py) and executes queued
-    searches in a single ``sim_search`` Pallas launch, queued gathers in a
-    single ``sim_gather`` launch, and queued lookups in a single fused
-    ``sim_fused_lookup`` launch, with the per-page randomization stream
-    regenerated in-kernel.  After warm-up only (Q, 2) query operands cross
+  * ``ShardedSsdBackend`` (sharded.py) — the kernel backend: a whole SSD of
+    ``channels x dies_per_channel`` chips whose stored pages stay *device
+    resident* in a ``PlaneStore`` arena (planestore.py).  Each flush phase
+    drains every chip's queue in ONE launch — searches and plans stacked
+    over the chip axis (vmap), lookups and gathers row-stacked — with the
+    per-page randomization stream regenerated in-kernel, and optional
+    coupling to the flash/ssd.py resource timelines for per-burst
+    latency/energy accounting.  After warm-up only the query operands cross
     host->device per flush; ``program_entries`` invalidates exactly the
     rewritten page's arena row through the engine's write observers.
 
@@ -39,26 +41,18 @@ ticket of the page resolves to the final image's ``BuiltPage`` and only ONE
 chip program executes (``BackendStats.programs`` /
 ``programs_coalesced``).  At ``flush()`` the queued programs run *first*
 (so commands flushed alongside them see the new images), and the kernel
-backends re-stage every programmed page's device-resident plane row in ONE
+backend re-stages every programmed page's device-resident plane row in ONE
 grouped scatter (``PlaneStore.stage_group``) instead of the per-page
 invalidate-then-restage round trip the eager ``program_entries`` path
 causes.  This is the backend half of the §VI "whole cache acts as a write
 buffer" configuration; the host half (coalescing across bursts, overlay
 reads) lives in ``repro.buffer.writebuffer``.
 
-Result delivery is *lazy* on the kernel backends: ``flush()`` dispatches
+Result delivery is *lazy* on the kernel backend: ``flush()`` dispatches
 the launches and attaches a ``LazyResultBatch`` to each ticket; the
 device->host transfer and host tail run at the first ``result()`` call of
 a burst, so JAX async dispatch overlaps staging of burst k+1 with device
 compute of burst k.
-
-A third implementation, ``ShardedSsdBackend`` (sharded.py), scales the
-same contract to a whole SSD: ``channels x dies_per_channel`` chips, each
-with its own plane-store arena and pending queue, drained in ONE stacked
-launch per burst (vmap over the chip axis) with optional coupling to the
-flash/ssd.py resource timelines for per-burst latency/energy accounting.
-The scalar and batched backends are its degenerate 1x1 cases and its
-bit-exactness references.
 
 Future backends the ROADMAP names (async, replicated) implement the same
 six methods: ``submit_search``, ``submit_gather``, ``submit_lookup``,
@@ -134,7 +128,7 @@ class BackendStats:
     lookups: int = 0           # fused lookup commands resolved
     plans: int = 0             # fused multi-pass plan commands resolved
     flushes: int = 0           # non-empty flush() calls
-    kernel_launches: int = 0   # device launches (batched backend only)
+    kernel_launches: int = 0   # device launches
     operand_programs: int = 0  # compiled arena operand gathers: one per
                                # launch (``PlaneStore.take``/``take2d``/
                                # ``take_lookup``)
@@ -158,7 +152,7 @@ class BackendStats:
                                # launch output)
     result_bytes: int = 0      # exact device->host result payload: 64 B per
                                # search/plan bitmap (per unique launch cell
-                               # on kernel backends — dedup'd commands share
+                               # on the kernel backend; dedup'd commands share
                                # one transfer), 64 B per gathered chunk,
                                # 64 B bitmap + 64 B value chunk (on hit) per
                                # lookup.  A fused PLAN pays 64 B/page where
@@ -168,7 +162,7 @@ class BackendStats:
 class LazyResultBatch:
     """Deferred host tail of one flushed launch.
 
-    The kernel backends resolve tickets *lazily*: ``flush()`` dispatches
+    The kernel backend resolves tickets *lazily*: ``flush()`` dispatches
     the launch and keeps its outputs as device arrays, attaching one of
     these to every ticket of the burst; the first ``result()`` call runs
     the host tail (device->host transfer, de-randomize/verify, ticket
@@ -208,7 +202,7 @@ class Ticket:
 
     ``result()`` on an unresolved ticket flushes the owning backend first,
     so eager callers never deadlock; batch-aware callers submit many
-    tickets and flush once.  On the kernel backends a flush attaches a
+    tickets and flush once.  On the kernel backend a flush attaches a
     :class:`LazyResultBatch` instead of a value — the launch output stays
     on-device until the first ``result()`` of the burst triggers the host
     transfer (``done`` reads True either way: the result is available
@@ -332,8 +326,8 @@ class MatchBackend(abc.ABC):
         """Run the queued programs against the chip model, in submit order.
 
         Resolves every ticket and returns the programmed page addresses so
-        kernel backends can re-stage them as ONE group (and timeline-coupled
-        backends can report the program group).  Called by ``flush()``
+        the kernel backend can re-stage them as ONE group (and report the
+        program group to its timeline).  Called by ``flush()``
         before any queued command executes — commands flushed in the same
         burst match against the new images, exactly like the eager path.
         """
@@ -414,13 +408,11 @@ def as_backend(chips_or_backend) -> MatchBackend:
 
 
 def make_backend(name: str, chips: SimChipArray, **kw) -> MatchBackend:
-    """Factory: ``scalar`` (reference), ``batched`` (single-arena Pallas
-    fast path) or ``sharded`` (channels x dies multi-chip SSD)."""
-    from .batched import BatchedKernelBackend
+    """Factory: ``scalar`` (reference) or ``sharded`` (channels x dies
+    multi-chip SSD, the kernel backend)."""
     from .scalar import ScalarBackend
     from .sharded import ShardedSsdBackend
-    backends = {"scalar": ScalarBackend, "batched": BatchedKernelBackend,
-                "sharded": ShardedSsdBackend}
+    backends = {"scalar": ScalarBackend, "sharded": ShardedSsdBackend}
     if name not in backends:
         raise ValueError(f"unknown backend {name!r}; pick from "
                          f"{sorted(backends)}")

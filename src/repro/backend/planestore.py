@@ -1,4 +1,4 @@
-"""Device-resident page-plane store for the batched kernel backend.
+"""Device-resident page-plane store for the kernel backend (sharded.py).
 
 The SiM chip's entire advantage is that stored pages never cross the bus —
 only queries and 64 B bitmaps move (paper §III-B).  The TPU analogue: keep

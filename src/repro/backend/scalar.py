@@ -3,12 +3,12 @@
 This is the existing numpy ``SimChip`` path behind the deferred-submission
 interface.  Every queued command walks the full functional model — latch
 pipeline, optimistic-open verdicts, ECC fallback — so it remains the
-bit-exact oracle the batched backend is validated against, and the only
-backend that models damaged pages end to end.
+bit-exact oracle the kernel backend (sharded.py) is validated against, and
+the only backend that models damaged pages end to end.
 A queued LOOKUP executes as the paper's §V-A command pair — a key-page
 search followed by a gather of the first matching user slot's chunk on the
 paired value page — through the same chip model, so it is the bit-exact
-oracle for the batched backend's fused single-launch lookup path.
+oracle for the kernel backend's fused single-launch lookup path.
 A queued PLAN executes as the per-pass split: one chip search per
 include/exclude pass, OR/AND-NOT combined on the controller — the
 bit-exact reference for the fused in-latch ``sim_plan`` kernel.

@@ -1,18 +1,17 @@
 """Sharded multi-chip SSD backend: channels x dies chips, one launch/burst.
 
-The scalar and batched backends drive what is effectively ONE chip's worth
-of device state; only the analytic timeline model (flash/ssd.py) knew the
-SSD has more than one die.  This backend is the refactor that turns "a chip
-model with fast kernels" into "an SSD": it owns ``channels x dies_per_channel``
-chips behind the same four-method ``MatchBackend`` contract and exploits
-their parallelism the way the paper's controller does (§VI-A, TCAM-SSD's
-channel-level framework).
+This is the kernel backend: the one that turns "a chip model with fast
+kernels" into "an SSD".  It owns ``channels x dies_per_channel`` chips
+behind the same four-method ``MatchBackend`` contract and exploits their
+parallelism the way the paper's controller does (§VI-A, TCAM-SSD's
+channel-level framework).  ``ScalarBackend`` (scalar.py) is the reference
+it is held to.
 
 Address space.  A global page address stripes across chips exactly like
 ``SimChipArray.route`` — ``chip = addr % n_chips``, ``local = addr // n_chips``
 (:func:`decompose` / :func:`compose`) — so stored images, and therefore
-every response, are bit-identical to the scalar/batched references over the
-same array.  The single-chip backends are the degenerate 1x1 case.
+every response, are bit-identical to the scalar reference over the same
+array, at every geometry down to 1x1.
 
 Per-chip state.  Every chip gets its own pending command queue and its own
 plane-arena namespace — per-chip row maps, dirty tracking and staged-byte
@@ -27,7 +26,7 @@ in a single device dispatch per phase:
     (chips, rows, ...) operands for ONE ``jax.vmap``-ed ``sim_search``
     launch over the chip axis.  Sharding also shrinks the work: a chip's
     queries match only its own resident pages, so the cross product is
-    ~1/chips of the single-arena launch — the kernel analogue of
+    ~1/chips of a one-chip launch — the kernel analogue of
     per-channel match engines, and where the >= 2x-at-16-chips throughput
     gate in benchmarks/kernel_micro.py comes from.
   * lookups — the paired ``sim_fused_lookup`` kernel is row-parallel
@@ -42,10 +41,10 @@ in a single device dispatch per phase:
     so the timeline charges ``n_passes`` match ops but only 64 B of
     match-mode bus payload per page — not 64 B per pass per page.
 
-Ticket resolution is lazy (see base.py/batched.py): every flush phase
-keeps its launch outputs device-resident and the host tail runs at the
-first ``result()`` of the burst, overlapping staging of the next burst
-with device compute of this one.  Timeline accounting stays at flush time
+Ticket resolution is lazy (see base.py): every flush phase keeps its
+launch outputs device-resident and the host tail runs at the first
+``result()`` of the burst, overlapping staging of the next burst with
+device compute of this one.  Timeline accounting stays at flush time
 — simulated SSD time is independent of when the host drains results.
 
 Timeline coupling.  Pass ``timeline=`` (or ``timeline=True``) to attach a
@@ -60,12 +59,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import trace
+from repro import kernels, trace
 from repro.core.bits import (CHUNKS_PER_PAGE, SLOTS_PER_CHUNK,
                              popcount_words, unpack_bitmap)
 from repro.core.commands import Command, LookupResponse, Op, SearchResponse
@@ -86,7 +86,7 @@ from repro.kernels.sim_search.sim_search import sim_search_kernel
 from repro.trace import span
 
 from .base import MatchBackend, Ticket
-from .batched import (resolve_gather_responses, resolve_lookup_responses,
+from .results import (resolve_gather_responses, resolve_lookup_responses,
                       resolve_plan_responses, resolve_search_responses,
                       snapshot_parities)
 from .planestore import PlaneStore, next_pow2, padded_rows
@@ -140,16 +140,61 @@ def _stacked_plan(lo, hi, q, m, f, ids, seeds, *, page_block: int,
     return jax.vmap(one_chip)(lo, hi, q, m, f, ids, seeds)
 
 
+# The operand keys searches and plans dedup by, per chip.
+_SEARCH_KEY = operator.attrgetter("query", "mask")
+_PLAN_KEY = operator.attrgetter("plan_include", "plan_exclude")
+
+
+def _search_operands(keys, c_pad):
+    """(C, Q, 2) query and mask rows from each chip's unique (query, mask)
+    keys; Q pads to a power of two."""
+    q_pad = max(next_pow2(len(k)) for k in keys)
+    q = np.zeros((c_pad, q_pad, 2), dtype=np.uint32)
+    m = np.zeros_like(q)
+    for i, k in enumerate(keys):
+        pairs = np.asarray(k, np.uint32)           # (Q, [query, mask], 2)
+        q[i, :len(k)] = pairs[:, 0]
+        m[i, :len(k)] = pairs[:, 1]
+    return q, m
+
+
+def _plan_operands(keys, c_pad):
+    """(C, G, P, 2) pass rows and (C, G, P) pass flags from each chip's
+    unique (include, exclude) groups; G and P pad to powers of two."""
+    g_pad = max(next_pow2(len(k)) for k in keys)
+    p_pad = next_pow2(max(max((len(i) + len(e) for i, e in k), default=1)
+                          for k in keys))
+    q = np.zeros((c_pad, g_pad, p_pad, 2), dtype=np.uint32)
+    m = np.zeros_like(q)
+    f = np.zeros((c_pad, g_pad, p_pad), dtype=np.uint32)
+    for i, k in enumerate(keys):
+        for gi, (inc, exc) in enumerate(k):
+            q[i, gi], m[i, gi], f[i, gi] = plan_pass_rows(inc, exc, p_pad)
+    return q, m, f
+
+
+@dataclasses.dataclass
+class _Placement:
+    """One stacked search or plan launch, laid out by
+    ``ShardedSsdBackend._flush_place``.  Lists run over the active chips."""
+    active: list[int]                   # chips with commands, slot order
+    addrs: list[list[int]]              # unique pages, in page-row order
+    keys: list[list[tuple]]             # unique operand keys, key-row order
+    stacked: list[tuple[int, int, int]]  # per command: (slot, ki, pi)
+    rows: int                           # page rows launched: C x N, padded
+    args: tuple                         # lo, hi, key operands..., ids, seeds
+
+
 class ShardedSsdBackend(MatchBackend):
     """channels x dies chips, per-chip queues, one stacked launch per burst.
 
     ``chips`` must hold ``channels * dies_per_channel`` chips (geometry
     defaults to one channel per chip).  Results are bit-identical to the
-    scalar/batched backends over the same array; like the batched backend
-    it reports ``open_verdict`` CLEAN unless a reliability tier is
-    attached (``enable_reliability``), in which case the flush runs the
-    full optimistic open burst and charges read-retries and full-page ECC
-    fallback reads on the flash timelines.
+    scalar backend over the same array.  It reports ``open_verdict``
+    CLEAN unless a reliability tier is attached (``enable_reliability``),
+    in which case the flush runs the full optimistic open burst and
+    charges read-retries and full-page ECC fallback reads on the flash
+    timelines.
     """
 
     # Bounded program retry budget: a seeded program-failure draw relocates
@@ -178,7 +223,8 @@ class ShardedSsdBackend(MatchBackend):
         self.page_block = page_block
         self.lookup_block = lookup_block
         self.use_kernel = use_kernel
-        self.interpret = interpret
+        self.interpret = (kernels.default_interpret() if interpret is None
+                          else interpret)
         if timeline is True:
             timeline = BurstTimeline(FlashParams(
                 channels=channels, dies_per_channel=dies_per_channel))
@@ -576,82 +622,88 @@ class ShardedSsdBackend(MatchBackend):
                               match_count=int(popcount_words(acc).sum()),
                               open_verdict=verdict)
 
-    # ------------------------------------------------------------- searches
-    def _flush_searches(self, searches, bursts, opens=None) -> None:
-        # Per chip: unique pages -> arena rows; unique (query, mask) ->
-        # operand rows; every command lands at one (chip, qi, pi) cell.
-        # Approximate-match voting re-senses each page vote_k times; the
-        # majority accumulates in-latch so still ONE bitmap crosses per
-        # command (mirrors the plan path's in-latch accumulation).
-        vf = self.reliability.vote_factor if self.reliability is not None \
-            else 1
+    # ---------------------------------------------------- searches and plans
+    def _flush_place(self, cmds, key_of, operands) -> _Placement:
+        """Lay out one burst of searches or plans as ONE stacked launch.
+
+        Per chip: unique pages -> arena rows, unique operand keys
+        (``key_of(cmd)``) -> key rows; every command lands at one
+        (chip slot, key row, page row) cell.  Chips with commands take the
+        slots in chip order, each slot's page rows pad to the common
+        pow2-of-block count, and the slots pad to a power of two.  One
+        staging pass over every chip's pages and one (C, N) gather fetch
+        the planes; ``operands(keys, c_pad)`` builds the key operands that
+        sit between the planes and the page ids in the launch's arguments.
+        """
         n = self.n_chips
         with span(trace.FLUSH_PLACE):
             addrs: list[list[int]] = [[] for _ in range(n)]
             page_rows: list[dict[int, int]] = [{} for _ in range(n)]
-            query_rows: list[dict[tuple, int]] = [{} for _ in range(n)]
-            q_pairs: list[list] = [[] for _ in range(n)]
-            m_pairs: list[list] = [[] for _ in range(n)]
-            placements = []                        # (chip, qi, pi)
-            for cmd, _ in searches:
+            key_rows: list[dict[tuple, int]] = [{} for _ in range(n)]
+            keys: list[list[tuple]] = [[] for _ in range(n)]
+            placements = []                        # (chip, ki, pi)
+            for cmd, _ in cmds:
                 c, _local = self.decompose(cmd.page_addr)
                 if cmd.page_addr not in page_rows[c]:
                     page_rows[c][cmd.page_addr] = len(addrs[c])
                     addrs[c].append(cmd.page_addr)
-                key = (cmd.query, cmd.mask)
-                if key not in query_rows[c]:
-                    query_rows[c][key] = len(q_pairs[c])
-                    q_pairs[c].append(cmd.query)
-                    m_pairs[c].append(cmd.mask)
-                placements.append((c, query_rows[c][key],
+                key = key_of(cmd)
+                if key not in key_rows[c]:
+                    key_rows[c][key] = len(keys[c])
+                    keys[c].append(key)
+                placements.append((c, key_rows[c][key],
                                    page_rows[c][cmd.page_addr]))
 
             active = [c for c in range(n) if addrs[c]]
             slot_of = {c: i for i, c in enumerate(active)}
             n_pad = max(padded_rows(len(addrs[c]), self.page_block)
                         for c in active)
-            q_pad = max(next_pow2(len(q_pairs[c])) for c in active)
             c_pad = next_pow2(len(active))
-            stacked = [(slot_of[c], qi, pi) for c, qi, pi in placements]
+            stacked = [(slot_of[c], ki, pi) for c, ki, pi in placements]
+            addrs = [addrs[c] for c in active]
+            keys = [keys[c] for c in active]
 
-        # One staging pass over every chip's pages, then one (C, N) gather.
         with span(trace.FLUSH_OPERANDS):
-            flat = [a for c in active for a in addrs[c]]
-            rows = self.store.rows_for(flat)
+            rows = self.store.rows_for([a for chip in addrs for a in chip])
             idx2d = np.zeros((c_pad, n_pad), np.int32)
             off = 0
-            for i, c in enumerate(active):
-                k = len(addrs[c])
-                idx2d[i, :k] = rows[off:off + k]
-                off += k
+            for i, chip in enumerate(addrs):
+                idx2d[i, :len(chip)] = rows[off:off + len(chip)]
+                off += len(chip)
             lo, hi, ids, seeds = self.store.take2d(idx2d)
             self.stats.operand_programs += 1
-            q = np.zeros((c_pad, q_pad, 2), dtype=np.uint32)
-            m = np.zeros_like(q)
-            for i, c in enumerate(active):
-                q[i, :len(q_pairs[c])] = np.asarray(q_pairs[c], np.uint32)
-                m[i, :len(m_pairs[c])] = np.asarray(m_pairs[c], np.uint32)
+            return _Placement(active, addrs, keys, stacked, c_pad * n_pad,
+                              (lo, hi, *operands(keys, c_pad), ids, seeds))
 
-        interp = self.interpret
-        if interp is None:
-            from repro.kernels import default_interpret
-            interp = default_interpret()
-        with span(trace.FLUSH_LAUNCH, kind="search", rows=c_pad * n_pad):
+    def _flush_account_senses(self, p: _Placement, bursts, vf: int) -> None:
+        """One sense per unique page (``vf`` under approximate-match
+        voting) and its open overhead on the bus, per chip, plus the
+        launch's counters."""
+        for c, addrs in zip(p.active, p.addrs):
+            k = len(addrs)
+            self.chips.chips[c].counters.array_reads += k  # one sense/page
+            b = self._burst(bursts, c)
+            b.senses += k * vf
+            b.bus_match_bytes += OPEN_OVERHEAD_BYTES * k
+        self.stats.kernel_launches += 1
+        self.stats.launched_rows += p.rows
+        self.stats.staged_pages += sum(len(a) for a in p.addrs)
+
+    def _flush_searches(self, searches, bursts, opens=None) -> None:
+        # Approximate-match voting re-senses each page vote_k times; the
+        # majority accumulates in-latch so still ONE bitmap crosses per
+        # command (mirrors the plan path's in-latch accumulation).
+        vf = self.reliability.vote_factor if self.reliability is not None \
+            else 1
+        p = self._flush_place(searches, _SEARCH_KEY, _search_operands)
+        with span(trace.FLUSH_LAUNCH, kind="search", rows=p.rows):
             out = _stacked_search(
-                lo, hi, q, m, ids, seeds, page_block=self.page_block,
-                use_kernel=self.use_kernel, interpret=interp)
+                *p.args, page_block=self.page_block,
+                use_kernel=self.use_kernel, interpret=self.interpret)
 
         with span(trace.FLUSH_ACCOUNT):
-            for c in active:
-                k = len(addrs[c])
-                self.chips.chips[c].counters.array_reads += k  # one sense/page
-                b = self._burst(bursts, c)
-                b.senses += k * vf
-                b.bus_match_bytes += OPEN_OVERHEAD_BYTES * k
-            self.stats.kernel_launches += 1
-            self.stats.launched_rows += c_pad * n_pad
-            self.stats.staged_pages += len(flat)
-            self.stats.staged_queries += sum(len(q_pairs[c]) for c in active)
+            self._flush_account_senses(p, bursts, vf)
+            self.stats.staged_queries += sum(len(k) for k in p.keys)
             self.stats.searches += len(searches)
             if len(searches) > 1:
                 self.stats.batched_searches += len(searches)
@@ -662,7 +714,7 @@ class ShardedSsdBackend(MatchBackend):
                 b.bus_match_bytes += BITMAP_BYTES
                 b.pcie_bytes += BITMAP_BYTES + QUERY_BYTES
 
-            def tail(out=out, searches=searches, stacked=stacked,
+            def tail(out=out, searches=searches, stacked=p.stacked,
                      rel=self.reliability, opens=opens):
                 with span(trace.TAIL_FETCH):
                     out = np.asarray(out)
@@ -672,90 +724,29 @@ class ShardedSsdBackend(MatchBackend):
             self._defer_all(searches, tail, kind="search",
                             flush=self.flush_seq)
 
-    # --------------------------------------------------------------- plans
     def _flush_plans(self, plans, bursts, opens=None) -> None:
         """Fused range plans, stacked across chips like searches.
 
-        Per chip: unique pages -> arena rows, unique (include, exclude)
-        pass tuples -> plan groups (the per-chip plan dedup mirroring the
-        query dedup).  ONE vmapped ``sim_plan`` launch evaluates every
-        chip's groups against its own resident pages.  On the simulated
-        bus a plan costs ``n_passes`` match ops but only ONE 64 B bitmap
-        per page — the in-latch accumulation (Fig 10) — where the per-pass
-        split path would cross 64 B per pass per page.
+        Unique (include, exclude) pass tuples are the plan's operand keys:
+        plan groups, the per-chip plan dedup mirroring the query dedup.
+        ONE vmapped ``sim_plan`` launch evaluates every chip's groups
+        against its own resident pages.  On the simulated bus a plan costs
+        ``n_passes`` match ops but only ONE 64 B bitmap per page — the
+        in-latch accumulation (Fig 10) — where the per-pass split path
+        would cross 64 B per pass per page.
         """
-        n = self.n_chips
         vf = self.reliability.vote_factor if self.reliability is not None \
             else 1
-        with span(trace.FLUSH_PLACE):
-            addrs: list[list[int]] = [[] for _ in range(n)]
-            page_rows: list[dict[int, int]] = [{} for _ in range(n)]
-            group_rows: list[dict[tuple, int]] = [{} for _ in range(n)]
-            groups: list[list[tuple]] = [[] for _ in range(n)]
-            placements = []                        # (chip, gi, pi)
-            for cmd, _ in plans:
-                c, _local = self.decompose(cmd.page_addr)
-                if cmd.page_addr not in page_rows[c]:
-                    page_rows[c][cmd.page_addr] = len(addrs[c])
-                    addrs[c].append(cmd.page_addr)
-                key = (cmd.plan_include, cmd.plan_exclude)
-                if key not in group_rows[c]:
-                    group_rows[c][key] = len(groups[c])
-                    groups[c].append(key)
-                placements.append((c, group_rows[c][key],
-                                   page_rows[c][cmd.page_addr]))
-
-            active = [c for c in range(n) if addrs[c]]
-            slot_of = {c: i for i, c in enumerate(active)}
-            n_pad = max(padded_rows(len(addrs[c]), self.page_block)
-                        for c in active)
-            g_pad = max(next_pow2(len(groups[c])) for c in active)
-            p_pad = next_pow2(max(max((len(i) + len(e) for i, e in groups[c]),
-                                      default=1) for c in active))
-            c_pad = next_pow2(len(active))
-            stacked = [(slot_of[c], gi, pi) for c, gi, pi in placements]
-
-        with span(trace.FLUSH_OPERANDS):
-            flat = [a for c in active for a in addrs[c]]
-            rows = self.store.rows_for(flat)
-            idx2d = np.zeros((c_pad, n_pad), np.int32)
-            off = 0
-            for i, c in enumerate(active):
-                k = len(addrs[c])
-                idx2d[i, :k] = rows[off:off + k]
-                off += k
-            lo, hi, ids, seeds = self.store.take2d(idx2d)
-            self.stats.operand_programs += 1
-            q = np.zeros((c_pad, g_pad, p_pad, 2), dtype=np.uint32)
-            m = np.zeros_like(q)
-            f = np.zeros((c_pad, g_pad, p_pad), dtype=np.uint32)
-            for i, c in enumerate(active):
-                for gi, (inc, exc) in enumerate(groups[c]):
-                    q[i, gi], m[i, gi], f[i, gi] = plan_pass_rows(inc, exc,
-                                                                  p_pad)
-
-        interp = self.interpret
-        if interp is None:
-            from repro.kernels import default_interpret
-            interp = default_interpret()
-        with span(trace.FLUSH_LAUNCH, kind="plan", rows=c_pad * n_pad):
+        p = self._flush_place(plans, _PLAN_KEY, _plan_operands)
+        with span(trace.FLUSH_LAUNCH, kind="plan", rows=p.rows):
             out = _stacked_plan(
-                lo, hi, q, m, f, ids, seeds, page_block=self.page_block,
-                use_kernel=self.use_kernel, interpret=interp)
+                *p.args, page_block=self.page_block,
+                use_kernel=self.use_kernel, interpret=self.interpret)
 
         with span(trace.FLUSH_ACCOUNT):
-            for c in active:
-                k = len(addrs[c])
-                self.chips.chips[c].counters.array_reads += k  # one sense/page
-                b = self._burst(bursts, c)
-                b.senses += k * vf
-                b.bus_match_bytes += OPEN_OVERHEAD_BYTES * k
-            self.stats.kernel_launches += 1
-            self.stats.launched_rows += c_pad * n_pad
-            self.stats.staged_pages += len(flat)
+            self._flush_account_senses(p, bursts, vf)
             self.stats.staged_queries += sum(len(i) + len(e)
-                                             for c in active
-                                             for i, e in groups[c])
+                                             for k in p.keys for i, e in k)
             self.stats.plans += len(plans)
             for cmd, _ in plans:
                 c, _local = self.decompose(cmd.page_addr)
@@ -764,7 +755,7 @@ class ShardedSsdBackend(MatchBackend):
                 b.bus_match_bytes += BITMAP_BYTES  # ...but ONE bitmap crosses
                 b.pcie_bytes += BITMAP_BYTES + QUERY_BYTES * cmd.n_passes
 
-            def tail(out=out, plans=plans, stacked=stacked,
+            def tail(out=out, plans=plans, stacked=p.stacked,
                      rel=self.reliability, opens=opens):
                 with span(trace.TAIL_FETCH):
                     out = np.asarray(out)

@@ -58,7 +58,7 @@ def chunk_stream_words_batch(page_addrs, chunk_ids, device_seeds, xp=np):
 
     Vectorized form of ``chunk_stream_words`` — one call de-randomizes every
     chunk of a whole gather/lookup burst instead of K per-chunk calls (the
-    host tail of the batched backend's flush).  ``device_seeds`` may be a
+    host tail of the sharded backend's flush).  ``device_seeds`` may be a
     scalar (one chip) or a (K,) array (burst spanning chips).
     """
     pages = xp.asarray(page_addrs, dtype=xp.uint32)

@@ -462,7 +462,7 @@ def replay(workload: Workload, backend,
     ``"serial"`` — the classic synchronous replay.  Reads accumulate into
     bursts of up to ``config.burst`` queries.  With ``fused=False`` the
     burst's searches flush as one batch, then its value gathers as a
-    second — two kernel launches on the batched backend.  With
+    second — two kernel launches on the sharded backend.  With
     ``fused=True`` every read becomes a ``submit_lookup`` and the whole
     burst resolves in one fused launch, with the depth-1 lazy pipeline
     overlapping adjacent bursts.  Writes are eager per-write programs, or

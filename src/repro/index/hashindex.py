@@ -8,7 +8,7 @@ no full-page read during redistribution.
 
 Device traffic flows through a MatchBackend; ``lookup_batch`` enqueues a
 burst of probes and flushes once (one kernel launch per phase on the
-batched backend).  Bucket pages are allocated sequentially, which on a
+sharded backend).  Bucket pages are allocated sequentially, which on a
 ``ShardedSsdBackend`` stripes them across channels x dies — a probe burst
 over many buckets therefore spreads over every chip and still executes as
 one stacked launch.

@@ -13,7 +13,7 @@ def stream_planes(page_base: int, n_pages: int, device_seed: int, xp=jnp,
     """Randomization stream for pages [page_base, page_base+n) as planes.
 
     ``page_ids``/``page_seeds`` (each (N,) uint32) override the contiguous
-    single-seed default — the per-page addressing the batched backend uses.
+    single-seed default — the per-page addressing the sharded backend uses.
     """
     if page_ids is None:
         page = (xp.arange(n_pages, dtype=xp.uint32)[:, None]

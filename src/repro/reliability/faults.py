@@ -17,7 +17,7 @@ Two orthogonal error sources, both fully determined by a single fault seed:
 
 Every random draw is keyed on ``(fault seed, chip seed, page, ...)`` SeedSequence
 entropy, never on a shared stream, so a sweep reproduces bit-identically
-across scalar/batched/sharded backends and across process restarts.
+across the scalar and sharded backends and across process restarts.
 
 The BER growth law is the usual retention power law: the raw BER grows as
 ``(1 + age / retention_ref_days) ** retention_exp`` and linearly-in-log with
@@ -75,8 +75,8 @@ class FaultModel:
         """Corrupt every programmed page of a SimChipArray in place.
 
         Flips ride ``SimChip.inject_bit_errors`` so the write observers fire
-        and any device-resident arena row is invalidated — batched/sharded
-        backends match against the same damaged planes as the scalar
+        and any device-resident arena row is invalidated — the sharded
+        backend matches against the same damaged planes as the scalar
         reference.  Returns the total number of injected error bits.
         """
         total = 0

@@ -123,18 +123,18 @@ def test_metered_timeline_records_real_intervals():
 
 
 def test_auditor_collects_findings():
-    aud = _Auditor("batched")
+    aud = _Auditor()
     aud.check(True, "SIM201", "timeline", "ok", "never recorded")
     aud.check(False, "SIM203", "replay", "no-result-bytes", "boom")
     aud.add("SIM201", "timeline", [("overlap:pcie", "double billed")])
     assert [(f.rule, f.path, f.slug) for f in aud.findings] == [
-        ("SIM203", "audit:batched", "no-result-bytes"),
-        ("SIM201", "audit:batched", "overlap:pcie")]
+        ("SIM203", "audit:sharded", "no-result-bytes"),
+        ("SIM201", "audit:sharded", "overlap:pcie")]
 
 
 # -------------------------------------------------------- the full audit
 def test_conservation_audit_clean_on_real_tree():
     """The seeded sharded replay's books must balance end to end: busy
     time, energy, bytes and fault accounting (the slow gate leg)."""
-    findings = run_conservation(kinds=("sharded",))
+    findings = run_conservation()
     assert findings == [], [f.format() for f in findings]

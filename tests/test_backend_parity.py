@@ -1,4 +1,4 @@
-"""Cross-backend parity: the batched Pallas backend must be bit-identical
+"""Cross-backend parity: the sharded Pallas backend must be bit-identical
 to the scalar SimChip reference on every programmed page — packed search
 bitmaps, match counts, gather chunk bytes/ids/parities — including the
 randomized=True in-kernel stream regeneration across chips with different
@@ -7,7 +7,7 @@ device seeds, and end-to-end through the index and workload layers.
 import numpy as np
 import pytest
 
-from repro.backend import BatchedKernelBackend, ScalarBackend, make_backend
+from repro.backend import ScalarBackend, ShardedSsdBackend, make_backend
 from repro.core.bits import chunk_bitmap_from_slot_bitmap, pair_to_u64
 from repro.core.commands import Command
 from repro.core.engine import SimChipArray
@@ -41,7 +41,7 @@ def _programmed_pair(seed=7):
 @pytest.fixture(scope="module")
 def backends():
     arr_s, arr_b, page_keys = _programmed_pair()
-    return ScalarBackend(arr_s), BatchedKernelBackend(arr_b), page_keys
+    return ScalarBackend(arr_s), ShardedSsdBackend(arr_b), page_keys
 
 
 def test_search_bitmaps_bit_identical(backends):
@@ -130,7 +130,7 @@ def test_range_plan_parity(backends):
     assert bb.stats.kernel_launches > 0
 
 
-def test_batched_launch_amortization(backends):
+def test_launch_amortization(backends):
     """A burst of searches over shared pages is one launch (§IV-E)."""
     _, bb, page_keys = backends
     before = bb.stats.kernel_launches
@@ -147,7 +147,7 @@ def _index_dataset():
     return keys, keys * np.uint64(13)
 
 
-@pytest.mark.parametrize("backend_name", ["scalar", "batched"])
+@pytest.mark.parametrize("backend_name", ["scalar", "sharded"])
 def test_btree_results_identical_on_both_backends(backend_name):
     keys, values = _index_dataset()
     be = make_backend(backend_name,
@@ -167,7 +167,7 @@ def test_btree_results_identical_on_both_backends(backend_name):
 def test_hash_index_parity():
     keys, values = _index_dataset()
     results = []
-    for name in ("scalar", "batched"):
+    for name in ("scalar", "sharded"):
         h = SimHashIndex(make_backend(
             name, SimChipArray(n_chips=8, pages_per_chip=512)))
         for k, v in zip(keys[:800], values[:800]):
@@ -228,14 +228,14 @@ def test_fused_lookup_parity_vs_scalar_split(backends):
 
 def test_planestore_invalidation_on_reprogram():
     """program -> search -> reprogram same page -> search must reflect the
-    new image on both backends, and the batched backend must restage only
+    new image on both backends, and the sharded backend must restage only
     the dirty row (4 KiB), nothing else."""
     rng = np.random.default_rng(9)
     keys_a = rng.integers(1, 2**62, 100, dtype=np.uint64)
     keys_b = rng.integers(1, 2**62, 100, dtype=np.uint64)
     arrays = [SimChipArray(n_chips=3, pages_per_chip=8, device_seed=17)
               for _ in range(2)]
-    backends_ = [ScalarBackend(arrays[0]), BatchedKernelBackend(arrays[1])]
+    backends_ = [ScalarBackend(arrays[0]), ShardedSsdBackend(arrays[1])]
     for arr in arrays:
         for p in range(6):
             arr.program_entries(p, keys_a)
@@ -285,7 +285,7 @@ def test_ycsb_run_functional_fused_identical():
     wl = generate(300, n_key_pages=6, read_ratio=0.8, alpha=0.5, seed=11)
     outs = {}
     for name, fused in (("scalar", False), ("scalar", True),
-                        ("batched", False), ("batched", True)):
+                        ("sharded", False), ("sharded", True)):
         arr = SimChipArray(n_chips=4, pages_per_chip=16, device_seed=3)
         outs[(name, fused)] = replay(wl, make_backend(name, arr),
                                      RunConfig(burst=32, fused=fused))
@@ -293,7 +293,7 @@ def test_ycsb_run_functional_fused_identical():
     for r in outs.values():
         np.testing.assert_array_equal(ref.read_values, r.read_values)
         np.testing.assert_array_equal(ref.read_hits, r.read_hits)
-    split, fused = outs[("batched", False)], outs[("batched", True)]
+    split, fused = outs[("sharded", False)], outs[("sharded", True)]
     assert fused.kernel_launches == fused.flushes          # 1 launch/burst
     assert split.kernel_launches == 2 * fused.kernel_launches
     assert fused.staged_bytes > 0 and ref.staged_bytes == 0
@@ -301,18 +301,18 @@ def test_ycsb_run_functional_fused_identical():
 
 def test_ycsb_run_functional_identical():
     """Full workload replay: identical read values on both backends, and
-    the batched backend actually batches (2 launches per read burst)."""
+    the sharded backend actually batches (2 launches per read burst)."""
     wl = generate(300, n_key_pages=6, read_ratio=0.8, alpha=0.5, seed=11)
     outs = {}
-    for name in ("scalar", "batched"):
+    for name in ("scalar", "sharded"):
         arr = SimChipArray(n_chips=4, pages_per_chip=16, device_seed=3)
         outs[name] = replay(wl, make_backend(name, arr),
                             RunConfig(burst=32))
     np.testing.assert_array_equal(outs["scalar"].read_values,
-                                  outs["batched"].read_values)
+                                  outs["sharded"].read_values)
     np.testing.assert_array_equal(outs["scalar"].read_hits,
-                                  outs["batched"].read_hits)
+                                  outs["sharded"].read_hits)
     assert outs["scalar"].read_hits[wl.ops == 0].all()
     assert outs["scalar"].kernel_launches == 0
-    assert outs["batched"].kernel_launches > 0
-    assert outs["batched"].kernel_launches <= outs["batched"].flushes
+    assert outs["sharded"].kernel_launches > 0
+    assert outs["sharded"].kernel_launches <= outs["sharded"].flushes

@@ -10,7 +10,7 @@ Contracts held here:
     aliases reading through to the nested sections;
   * **bit-parity anchor** — ``RunConfig.event_serial()`` (one stream,
     zero inter-arrival, FIFO) replays bit-identically to
-    ``mode="serial"`` across scalar/batched/sharded x split/fused x
+    ``mode="serial"`` across scalar/sharded (4x1, 2x2) x split/fused x
     buffered/reliability configs: same values, hits, errors, programs
     AND the same flush grouping (reliability epochs depend on it);
   * **determinism** — same seeds => identical event trace and report;
@@ -156,7 +156,7 @@ def _assert_parity(rs, re):
 
 @pytest.mark.parametrize("fused", [False, True])
 @pytest.mark.parametrize("buffered", [False, True])
-@pytest.mark.parametrize("name", ["scalar", "batched", "sharded"])
+@pytest.mark.parametrize("name", ["scalar", "sharded4x1", "sharded"])
 def test_event_serial_bit_parity(name, fused, buffered):
     wl = generate(300, n_key_pages=8, read_ratio=0.5, alpha=0.9, seed=7,
                   scan_ratio=0.05)
@@ -170,7 +170,8 @@ def test_event_serial_bit_parity(name, fused, buffered):
                 channels=2, dies_per_channel=2,
                 pages_per_chip=max(wl.n_index_pages // 4 + 1, 8),
                 device_seed=3)
-        return _mk(name, n_chips=4,
+        # sharded4x1: make_backend gives each of the 4 chips its own channel
+        return _mk(name.removesuffix("4x1"), n_chips=4,
                    pages=max(wl.n_index_pages // 4 + 1, 8))
 
     _assert_parity(replay(wl, mk(), RunConfig(**kw)),

@@ -3,10 +3,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-import repro.backend.batched as batched_mod
-from repro.analysis.launch_audit import (FORBIDDEN_PRIMITIVES, audit_backend,
-                                         iter_eqns, record_launches,
-                                         summarize_jaxpr)
+import repro.backend.sharded as sharded_mod
+from repro.analysis.launch_audit import (CHIP_COUNTS, FORBIDDEN_PRIMITIVES,
+                                         audit_backend, iter_eqns,
+                                         record_launches, summarize_jaxpr)
 
 
 # ------------------------------------------------------------ jaxpr walking
@@ -48,59 +48,51 @@ def test_summary_bytes_and_signature():
 
 # -------------------------------------------------------------- the recorder
 def test_recorder_restores_entry_points():
-    orig = batched_mod.sim_search
-    with record_launches("batched") as records:
-        assert batched_mod.sim_search is not orig
-    assert batched_mod.sim_search is orig
+    orig = sharded_mod._stacked_search
+    with record_launches() as records:
+        assert sharded_mod._stacked_search is not orig
+    assert sharded_mod._stacked_search is orig
     assert records == []
 
 
 # ----------------------------------------------------------- the full audits
-def test_batched_audit_is_clean():
-    findings = audit_backend("batched", hlo=True)
-    assert findings == [], "\n".join(f.format() for f in findings)
-
-
-def test_sharded_audit_is_clean():
-    findings = audit_backend("sharded", hlo=True)
+@pytest.mark.parametrize("n_chips", CHIP_COUNTS)
+def test_sharded_audit_is_clean(n_chips):
+    findings = audit_backend(n_chips, hlo=True)
     assert findings == [], "\n".join(f.format() for f in findings)
 
 
 # ------------------------------------------------------------- gate tripping
 def test_second_pallas_call_trips_sim101(monkeypatch):
-    """Doctor sim_search to launch twice; the audit must flag every search
-    phase (value-identical, so only the launch *shape* differs)."""
-    orig = batched_mod.sim_search
+    """Doctor the search launch to run twice; the audit must flag every
+    search phase (value-identical, so only the launch *shape* differs)."""
+    orig = sharded_mod._stacked_search
 
     def doubled(*args, **kwargs):
         first = orig(*args, **kwargs)
         again = orig(*args, **kwargs)
         return first | (again & 0)     # second launch contributes nothing
 
-    monkeypatch.setattr(batched_mod, "sim_search", doubled)
-    findings = audit_backend("batched", hlo=False)
+    monkeypatch.setattr(sharded_mod, "_stacked_search", doubled)
+    findings = audit_backend(hlo=False)
     bad = [f for f in findings
-           if f.rule == "SIM101" and f.slug == "pallas-count:sim_search"]
-    assert bad, "doctored double-launch sim_search was not flagged"
+           if f.rule == "SIM101" and f.slug == "pallas-count:_stacked_search"]
+    assert bad, "doctored double-launch _stacked_search was not flagged"
     assert {f.symbol for f in bad} >= {"search-cold", "search-warm"}
 
 
 def test_callback_in_flush_trips_sim102(monkeypatch):
-    """Doctor sim_search with a host callback; the audit must flag it."""
-    orig = batched_mod.sim_search
+    """Doctor the search launch with a host callback; the audit must flag
+    it."""
+    orig = sharded_mod._stacked_search
 
-    def with_callback(lo, hi, q, m, **kwargs):
+    def with_callback(lo, hi, q, m, *args, **kwargs):
         probe = jax.pure_callback(
             lambda v: v, jax.ShapeDtypeStruct(q.shape, q.dtype), q)
-        return orig(lo, hi, probe, m, **kwargs)
+        return orig(lo, hi, probe, m, *args, **kwargs)
 
-    monkeypatch.setattr(batched_mod, "sim_search", with_callback)
-    findings = audit_backend("batched", hlo=False)
+    monkeypatch.setattr(sharded_mod, "_stacked_search", with_callback)
+    findings = audit_backend(hlo=False)
     assert any(f.rule == "SIM102" and "pure_callback" in f.message
                for f in findings)
 
-
-def test_unknown_backend_kind_rejected():
-    with pytest.raises(KeyError):
-        with record_launches("scalar"):
-            pass

@@ -1,7 +1,7 @@
 """Op.PLAN — fused in-latch range-plan execution — and the lazy result path.
 
 The contract (ISSUE 4): PLAN results are bit-identical across the scalar
-(per-pass split reference), batched and sharded backends AND identical to
+(per-pass split reference) and sharded backends AND identical to
 the per-pass ``evaluate_plan_per_pass`` combine, for exact and approximate
 plans; device->host result bytes drop by the plan's pass count; ticket
 resolution is lazy (launch outputs stay on-device until the first
@@ -12,8 +12,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-from repro.backend import (BatchedKernelBackend, ScalarBackend,
-                           ShardedSsdBackend, make_backend)
+from repro.backend import ScalarBackend, ShardedSsdBackend, make_backend
 from repro.core.commands import Command, Op
 from repro.core.engine import SimChipArray
 from repro.core.range_query import (MaskedQuery, RangePlan,
@@ -46,8 +45,6 @@ def backends():
     page_keys = _page_keys()
     mk = {
         "scalar": lambda: ScalarBackend(
-            SimChipArray(n_chips=4, pages_per_chip=8, device_seed=31)),
-        "batched": lambda: BatchedKernelBackend(
             SimChipArray(n_chips=4, pages_per_chip=8, device_seed=31)),
         "sharded4x2": lambda: ShardedSsdBackend.from_geometry(
             channels=4, dies_per_channel=2, pages_per_chip=8,
@@ -97,22 +94,21 @@ def test_plan_burst_is_one_launch_with_dedup(backends):
     bes, page_keys = backends
     plan_a = exact_range(1000, 2**40, width=64)
     plan_b = approximate_range(1000, 2**40, width=64)
-    for name in ("batched", "sharded4x2"):
-        be = bes[name]
-        before = be.stats.kernel_launches
-        tickets = [be.submit_plan(Command.plan(p, pl.include, pl.exclude))
-                   for pl in (plan_a, plan_b) for p in range(N_PAGES)]
-        be.flush()
-        assert be.stats.kernel_launches == before + 1
-        assert all(t.done for t in tickets)
-        # Same plan twice on the same page -> same launch cell, shared copy.
-        t1 = be.submit_plan(Command.plan(3, plan_a.include, plan_a.exclude))
-        t2 = be.submit_plan(Command.plan(3, plan_a.include, plan_a.exclude))
-        rb = be.stats.result_bytes
-        be.flush()
-        np.testing.assert_array_equal(t1.result().bitmap_words,
-                                      t2.result().bitmap_words)
-        assert be.stats.result_bytes - rb == 64   # one transfer, not two
+    be = bes["sharded4x2"]
+    before = be.stats.kernel_launches
+    tickets = [be.submit_plan(Command.plan(p, pl.include, pl.exclude))
+               for pl in (plan_a, plan_b) for p in range(N_PAGES)]
+    be.flush()
+    assert be.stats.kernel_launches == before + 1
+    assert all(t.done for t in tickets)
+    # Same plan twice on the same page -> same launch cell, shared copy.
+    t1 = be.submit_plan(Command.plan(3, plan_a.include, plan_a.exclude))
+    t2 = be.submit_plan(Command.plan(3, plan_a.include, plan_a.exclude))
+    rb = be.stats.result_bytes
+    be.flush()
+    np.testing.assert_array_equal(t1.result().bitmap_words,
+                                  t2.result().bitmap_words)
+    assert be.stats.result_bytes - rb == 64       # one transfer, not two
 
 
 def test_plan_result_bytes_drop_by_pass_count(backends):
@@ -122,7 +118,7 @@ def test_plan_result_bytes_drop_by_pass_count(backends):
     plan = _plans(page_keys)["exact"]
     assert plan.n_passes > 10
     pages = list(range(N_PAGES))
-    be = bes["batched"]
+    be = bes["sharded4x2"]
     before = be.stats.result_bytes
     evaluate_plan_on_pages(be, plan, pages)
     fused_bytes = be.stats.result_bytes - before
@@ -178,7 +174,7 @@ def test_lazy_ticket_out_of_order_resolution(backends):
     """Two bursts flushed back-to-back, the first drained AFTER the second:
     lazy batches must resolve independently and bit-identically."""
     bes, page_keys = backends
-    be = bes["batched"]
+    be = bes["sharded4x2"]
     ref = bes["scalar"]
     cmds_a = [Command.search(p, int(page_keys[p][5])) for p in range(6)]
     cmds_b = [Command.search(p, int(page_keys[p][6])) for p in range(6)]
@@ -196,7 +192,7 @@ def test_lazy_ticket_survives_interleaved_reprogram(backends):
     """A reprogram AFTER a flush must not leak into that flush's deferred
     results — the launch captured the pre-write plane snapshot."""
     page_keys = _page_keys(seed=23)
-    be = _programmed(page_keys, lambda: BatchedKernelBackend(
+    be = _programmed(page_keys, lambda: ShardedSsdBackend(
         SimChipArray(n_chips=4, pages_per_chip=8, device_seed=9)))
     sc = _programmed(page_keys, lambda: ScalarBackend(
         SimChipArray(n_chips=4, pages_per_chip=8, device_seed=9)))
@@ -222,7 +218,7 @@ def test_lazy_lookup_parity_survives_interleaved_reprogram():
     rng = np.random.default_rng(3)
     keys = rng.integers(1, 2**50, 100, dtype=np.uint64)
     vals = rng.integers(1, 2**50, 100, dtype=np.uint64)
-    for name in ("scalar", "batched"):
+    for name in ("scalar", "sharded"):
         be = make_backend(name, SimChipArray(n_chips=2, pages_per_chip=8,
                                              device_seed=1))
         be.program_entries(0, keys)
@@ -249,7 +245,7 @@ def test_ycsb_scan_replay_bit_identical():
     for name, make in {
         "scalar": lambda: make_backend("scalar", SimChipArray(
             n_chips=4, pages_per_chip=16, device_seed=3)),
-        "batched": lambda: make_backend("batched", SimChipArray(
+        "sharded4x1": lambda: make_backend("sharded", SimChipArray(
             n_chips=4, pages_per_chip=16, device_seed=3)),
         "sharded2x2": lambda: ShardedSsdBackend.from_geometry(
             channels=2, dies_per_channel=2, pages_per_chip=16,

@@ -2,8 +2,8 @@
 
 Covers the §IV-C pipeline end to end: the vectorized CRC kernels against
 their per-byte oracles, `FaultModel` determinism, the typed
-`UncorrectableReadError` channel behaving identically on the scalar,
-batched and sharded backends (below-t, above-t, header-only and body-only
+`UncorrectableReadError` channel behaving identically on the scalar
+and sharded backends (below-t, above-t, header-only and body-only
 corruption), reprogram clearing injected damage, retention refreshes, and
 the two sweep-level contracts in miniature: a verified replay produces
 zero wrong results against the analytic oracle, an unverified noisy
@@ -31,7 +31,7 @@ from repro.reliability import (FaultModel, ReliabilityPolicy,
 from repro.frontend import RunConfig, replay
 from repro.workload.ycsb import generate
 
-BACKENDS = ("scalar", "batched", "sharded")
+BACKENDS = ("scalar", "sharded")
 T_CORRECTABLE = 40
 
 
@@ -188,11 +188,11 @@ def test_reprogram_clears_injected_errors():
 
 
 # ----------------------------------------------------- functional replays
-def _functional(name, wl, policy, fault, **kw):
+def _functional(name, wl, policy, fault, use_kernel=False, **kw):
     arr = SimChipArray(
         n_chips=2, pages_per_chip=max(wl.n_index_pages // 2 + 1, 8),
         device_seed=3)
-    bkw = {"use_kernel": False} if name == "sharded" else {}
+    bkw = {"use_kernel": use_kernel} if name == "sharded" else {}
     rel = ReliabilityState(policy, fault)
     res = replay(wl, make_backend(name, arr, **bkw),
                  RunConfig.reliable(rel, burst=16, **kw))
@@ -257,10 +257,10 @@ def test_write_buffer_replay_parity_under_faults():
     fault = FaultModel(seed=11, base_ber=1e-4, retention_days=45.0,
                        sense_ber=2e-4)
     runs = {}
-    for name in ("scalar", "batched"):
+    for name in ("scalar", "sharded"):
         for buffered in (False, True):
-            res, _ = _functional(name, wl, policy, fault, fused=True,
-                                 write_buffer=buffered)
+            res, _ = _functional(name, wl, policy, fault, use_kernel=True,
+                                 fused=True, write_buffer=buffered)
             runs[name, buffered] = res
     ref = runs["scalar", False]
     for (name, buffered), r in runs.items():
@@ -268,4 +268,4 @@ def test_write_buffer_replay_parity_under_faults():
                                       err_msg=f"{name} buffered={buffered}")
         np.testing.assert_array_equal(r.read_hits, ref.read_hits)
         np.testing.assert_array_equal(r.read_errors, ref.read_errors)
-    assert runs["batched", True].programs <= runs["batched", False].programs
+    assert runs["sharded", True].programs <= runs["sharded", False].programs

@@ -4,13 +4,12 @@ one-dispatch-per-burst, index wiring and timeline-coupled accounting.
 The sharded backend owns channels x dies chips behind the MatchBackend
 contract; stored-image randomization cancels between program and search,
 so responses must be bit-identical across EVERY geometry — 1x1, 4x4 —
-and against the scalar/batched single-arena references.
+and against the scalar reference.
 """
 import numpy as np
 import pytest
 
-from repro.backend import (BatchedKernelBackend, ScalarBackend,
-                           ShardedSsdBackend, make_backend)
+from repro.backend import ScalarBackend, ShardedSsdBackend, make_backend
 from repro.backend.sharded import compose, decompose
 from repro.core.commands import Command
 from repro.core.engine import SimChipArray
@@ -85,15 +84,13 @@ def _programmed(page_keys, make):
 
 @pytest.fixture(scope="module")
 def backends():
-    """scalar / batched (shared 16-chip array layout) + sharded 1x1 and
-    4x4 — four backends over identically-keyed page sets."""
+    """scalar (16-chip array layout) + sharded 1x1 and 4x4 — three
+    backends over identically-keyed page sets."""
     rng = np.random.default_rng(7)
     page_keys = [rng.integers(1, 2**62, ENTRIES_PER_PAGE, dtype=np.uint64)
                  for _ in range(N_PAGES)]
     mk = {
         "scalar": lambda: ScalarBackend(
-            SimChipArray(n_chips=16, pages_per_chip=8, device_seed=31)),
-        "batched": lambda: BatchedKernelBackend(
             SimChipArray(n_chips=16, pages_per_chip=8, device_seed=31)),
         "sharded1x1": lambda: ShardedSsdBackend.from_geometry(
             channels=1, pages_per_chip=N_PAGES, device_seed=31),
@@ -269,8 +266,6 @@ def ycsb_replays():
     outs = {}
     for name, make in {
         "scalar": lambda: make_backend("scalar", SimChipArray(
-            n_chips=4, pages_per_chip=16, device_seed=3)),
-        "batched": lambda: make_backend("batched", SimChipArray(
             n_chips=4, pages_per_chip=16, device_seed=3)),
         "sharded1x1": lambda: ShardedSsdBackend.from_geometry(
             channels=1, pages_per_chip=64, device_seed=3, timeline=True),

@@ -10,7 +10,7 @@ Contracts held here:
     plane-store staging ships each programmed row exactly once;
   * buffered ``replay`` — bit-identical ``read_values``/
     ``read_hits`` to the eager unbuffered scalar reference across scalar /
-    batched / sharded x split / fused, with ``programs < n_writes`` on the
+    sharded x split / fused, with ``programs < n_writes`` on the
     skewed YCSB-A stream (hot-page coalescing) and overlay reads counted;
   * the timing executor ``run()`` — YCSB-E scans are match-mode multi-page
     READS: a scan-bearing workload issues zero writes and zero programs
@@ -80,7 +80,7 @@ def test_high_water_validation():
 # Deferred Op.PROGRAM on the backends
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("name", ["scalar", "batched"])
+@pytest.mark.parametrize("name", ["scalar", "sharded"])
 def test_submit_program_coalesces_last_wins(name):
     arr = SimChipArray(n_chips=2, pages_per_chip=8)
     be = make_backend(name, arr)
@@ -99,7 +99,7 @@ def test_submit_program_coalesces_last_wins(name):
 def test_programs_execute_before_flushed_searches():
     """A search flushed alongside a program of its page must match the NEW
     image — same ordering as the eager program_entries path."""
-    for name in ("scalar", "batched"):
+    for name in ("scalar", "sharded"):
         arr = SimChipArray(n_chips=2, pages_per_chip=8)
         be = make_backend(name, arr)
         keys = np.arange(1, 101, dtype=np.uint64)
@@ -112,7 +112,7 @@ def test_programs_execute_before_flushed_searches():
 
 def test_grouped_staging_ships_each_programmed_row_once():
     arr = SimChipArray(n_chips=4, pages_per_chip=8)
-    be = make_backend("batched", arr)
+    be = make_backend("sharded", arr)
     keys = np.arange(1, 405, dtype=np.uint64)
     for p in range(6):
         be.program_entries(p, keys + np.uint64(p))
@@ -178,9 +178,9 @@ def test_read_your_writes_served_from_buffer():
                                                device_seed=3))
 
     ref = replay(wl, mk("scalar"), RunConfig(burst=64))
-    for name in ("scalar", "batched"):
+    for name in ("scalar", "sharded"):
         r = replay(wl, mk(name), RunConfig(
-            burst=64, fused=(name == "batched"), write_buffer=True))
+            burst=64, fused=(name == "sharded"), write_buffer=True))
         np.testing.assert_array_equal(ref.read_values, r.read_values)
         np.testing.assert_array_equal(ref.read_hits, r.read_hits)
         # reads 2 and 4 hit the dirty page in the buffer; key 900 lives on
@@ -199,7 +199,7 @@ def test_high_water_groups_programs_mid_stream():
     keys = [0, 3, 7 * KEYS_PER_PAGE, 7 * KEYS_PER_PAGE + 9] \
         + [p * KEYS_PER_PAGE for p in range(1, 7)]
     wl = _manual_workload([1] * 10, keys, n_key_pages)
-    be = make_backend("batched", SimChipArray(n_chips=2, pages_per_chip=16,
+    be = make_backend("sharded", SimChipArray(n_chips=2, pages_per_chip=16,
                                               device_seed=1))
     r = replay(wl, be, RunConfig.buffered(burst=64, write_high_water=4))
     assert r.write_flushes == 2
@@ -210,8 +210,8 @@ def test_high_water_groups_programs_mid_stream():
 @pytest.mark.parametrize("fused", [False, True])
 def test_ycsb_a_buffered_parity_all_backends(fused):
     """YCSB-A (read_ratio=0.5, alpha=0.9): buffered replay is bit-identical
-    to the eager unbuffered scalar reference on scalar, batched and
-    sharded backends, with measurable hot-page coalescing."""
+    to the eager unbuffered scalar reference on the scalar and sharded
+    backends, with measurable hot-page coalescing."""
     wl = generate(400, n_key_pages=8, read_ratio=0.5, alpha=0.9, seed=11)
     pages_per_chip = max(wl.n_index_pages // 4 + 1, 8)
 
@@ -225,7 +225,7 @@ def test_ycsb_a_buffered_parity_all_backends(fused):
 
     ref = replay(wl, mk("scalar"), RunConfig(burst=64))
     assert ref.programs == ref.n_writes
-    for name in ("scalar", "batched", "sharded"):
+    for name in ("scalar", "sharded"):
         r = replay(wl, mk(name), RunConfig.buffered(
             burst=64, fused=fused, write_high_water=8))
         np.testing.assert_array_equal(ref.read_values, r.read_values)
@@ -261,7 +261,7 @@ def test_buffered_scan_workload_parity():
             n_chips=4, pages_per_chip=pages_per_chip, device_seed=3))
 
     ref = replay(wl, mk("scalar"), RunConfig(burst=64))
-    r = replay(wl, mk("batched"), RunConfig.buffered(
+    r = replay(wl, mk("sharded"), RunConfig.buffered(
         burst=64, fused=True, write_high_water=8))
     np.testing.assert_array_equal(ref.read_values, r.read_values)
     np.testing.assert_array_equal(ref.scan_counts, r.scan_counts)
@@ -329,7 +329,7 @@ def test_flush_raises_on_unresolved_program_tickets():
 
 def test_flush_counts_resolved_programs():
     chips = SimChipArray(n_chips=2, pages_per_chip=16, device_seed=5)
-    backend = make_backend("batched", chips, page_block=8)
+    backend = make_backend("sharded", chips, page_block=8)
     buf = WriteBuffer(high_water=4)
     buf.put(0, np.arange(8, dtype=np.uint64))
     buf.put(1, np.arange(8, 16, dtype=np.uint64))
