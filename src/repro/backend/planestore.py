@@ -20,6 +20,12 @@ one 4 KiB row host->device; untouched pages ship zero bytes.  The arena
 capacity grows by power-of-two blocks and existing rows are carried over
 with a device-side copy, so growth never re-ships resident pages.
 
+Staged rows reach the arena through ``scatter_planes``: one compiled
+program per group of at most ``SLAB`` rows that writes all four planes in
+place (the arena is donated).  A group's row count is padded to
+``padded_rows`` by repeating its last row, so drains of similar size share
+one compiled shape; a larger group goes in slabs of ``SLAB`` rows.
+
 ``staged_bytes``/``staged_rows`` count actual host->device page-plane
 traffic; the kernel-micro benchmark asserts they stop growing once the
 working set is warm (the zero-restage claim of the ROADMAP's hot-path
@@ -27,6 +33,7 @@ mandate).
 """
 from __future__ import annotations
 
+import functools
 import weakref
 
 import jax
@@ -59,6 +66,26 @@ def _rows(plane, ridx):
     drops the negative-index fix-up and the out-of-bounds clamp."""
     return plane.at[ridx].get(mode="promise_in_bounds",
                               wrap_negative_indices=False)
+
+
+#: Most rows one ``scatter_planes`` call writes.  A larger group (the first
+#: staging of a whole table makes one) goes in slabs of this many rows, so
+#: its host->device operands stay a slab's size.
+SLAB = 4096
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+def scatter_planes(lo, hi, ids, seeds, ridx, new_lo, new_hi, new_ids,
+                   new_seeds):
+    """Arena rows ``ridx`` of all four planes set to the update rows, as ONE
+    compiled program.  The planes are donated, so the rows are written in
+    place: an eager ``.at[].set`` cannot donate and copies every plane
+    whole.  ``ridx`` may repeat a row whose update rows repeat too."""
+    def put(plane, rows):
+        return plane.at[ridx].set(rows, mode="promise_in_bounds",
+                                  wrap_negative_indices=False)
+    return (put(lo, new_lo), put(hi, new_hi), put(ids, new_ids),
+            put(seeds, new_seeds))
 
 
 @jax.jit
@@ -183,12 +210,12 @@ class PlaneStore:
         The deferred write path (``MatchBackend.submit_program``) calls this
         right after its grouped chip programs: every listed page that is
         resident-and-dirty, or not yet resident, ships in a single
-        ``_stage`` scatter — N programs cost one ``.at[idx].set`` per plane
-        instead of N per-page invalidate-then-restage round trips through
-        later ``rows_for`` calls.  Clean resident pages are skipped, and
-        dirty restages enter ``staged_log``, both exactly as in
-        ``rows_for`` — which does all the work here; this entry point only
-        discards the row indices.  Returns the number of rows staged.
+        ``_stage`` call — N programs cost one ``scatter_planes`` launch (one
+        per ``SLAB`` rows) instead of N per-page invalidate-then-restage
+        round trips through later ``rows_for`` calls.  Clean resident pages
+        are skipped, and dirty restages enter ``staged_log``, both exactly
+        as in ``rows_for`` — which does all the work here; this entry point
+        only discards the row indices.  Returns the number of rows staged.
         """
         before = self.staged_rows
         self.rows_for([int(a) for a in page_addrs])
@@ -196,10 +223,16 @@ class PlaneStore:
 
     def _stage(self, addrs: list[int]) -> None:
         """Ship the listed pages' planes host->device (the only page bytes
-        that ever cross after warm-up: new rows and dirty rows)."""
-        with span(STAGE, rows=len(addrs)):
-            idx = jnp.asarray(np.array([self._row[a] for a in addrs],
-                                       np.int32))
+        that ever cross after warm-up: new rows and dirty rows).
+
+        One ``scatter_planes`` call per slab of at most ``SLAB`` rows, its
+        row count padded to ``padded_rows`` by repeating the slab's last
+        row; the counters count the listed pages, never the pads."""
+        n = len(addrs)
+        cuts = [(s, min(s + SLAB, n)) for s in range(0, n, SLAB)]
+        sizes = [padded_rows(e - s, self.block) for s, e in cuts]
+        with span(STAGE, rows=n, padded=sum(sizes)):
+            rows = np.array([self._row[a] for a in addrs], np.int32)
             raws, ids, seeds = [], [], []
             for a in addrs:
                 chip, local = self.chips.route(a)
@@ -207,15 +240,20 @@ class PlaneStore:
                 ids.append(local)
                 seeds.append(chip.device_seed & 0xFFFFFFFF)
             lo, hi = pages_to_planes(np.stack(raws))
-            self._lo = self._lo.at[idx].set(jnp.asarray(lo))
-            self._hi = self._hi.at[idx].set(jnp.asarray(hi))
-            self._ids = self._ids.at[idx].set(
-                jnp.asarray(np.asarray(ids, np.uint32)[:, None]))
-            self._seeds = self._seeds.at[idx].set(
-                jnp.asarray(np.asarray(seeds, np.uint32)[:, None]))
+            ids = np.asarray(ids, np.uint32)[:, None]
+            seeds = np.asarray(seeds, np.uint32)[:, None]
+            for (s, e), size in zip(cuts, sizes):
+                sel = np.minimum(np.arange(s, s + size), e - 1)
+                self._lo, self._hi, self._ids, self._seeds = scatter_planes(
+                    self._lo, self._hi, self._ids, self._seeds, rows[sel],
+                    lo[sel], hi[sel], ids[sel], seeds[sel])
+                if e < n:
+                    # Let a slab land before the next ships: otherwise
+                    # several slabs' operands can be on the device at once.
+                    self._lo.block_until_ready()
         self._dirty.difference_update(addrs)
-        self.staged_rows += len(addrs)
-        self.staged_bytes += len(addrs) * PAGE_BYTES
+        self.staged_rows += n
+        self.staged_bytes += n * PAGE_BYTES
 
     # ----------------------------------------------------------------- access
     # Each access method is one call of a compiled gather with a host-built

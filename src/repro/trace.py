@@ -23,7 +23,7 @@ FLUSH_PLACE = "sim.flush.place"        # queues, failover, command placement
 FLUSH_OPERANDS = "sim.flush.operands"  # rows_for, compiled gather, operands
 FLUSH_LAUNCH = "sim.flush.launch"      # kind=<lookup|plan|search|gather>, rows
 FLUSH_ACCOUNT = "sim.flush.account"    # ChipBurst records, parities, stats
-STAGE = "sim.stage"                    # PlaneStore._stage, rows=<pages>
+STAGE = "sim.stage"                    # PlaneStore._stage, rows=, padded=
 TAIL = "sim.tail"                      # LazyResultBatch.run, flush=, kind=
 TAIL_FETCH = "sim.tail.fetch"          # device->host copy of launch outputs
 

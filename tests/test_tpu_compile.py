@@ -63,3 +63,24 @@ def test_main_path_kernel_carries_its_name(one_chip, launch):
     calls = [line.split("=", 1)[0].strip() for line in text.splitlines()
              if " custom-call(" in line and "tpu_custom_call" in line]
     assert calls and all(c.startswith(f"%{launch}.") for c in calls), calls
+
+
+def test_plane_scatter_updates_the_arena_in_place_on_v5e(one_chip):
+    """``scatter_planes`` at the YCSB cells' arena (65,536 rows) and a
+    drain's 32 padded rows: the four donated planes alias the four outputs
+    and no plane-sized temporary is made, so a restage writes its rows in
+    place instead of copying the arena."""
+    import jax
+    import jax.numpy as jnp
+    from repro.backend.planestore import scatter_planes
+
+    def arg(*shape, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cap, rows = 65536, 32
+    planes = (arg(cap, 512), arg(cap, 512), arg(cap, 1), arg(cap, 1))
+    updates = (arg(rows, dtype=jnp.int32), arg(rows, 512), arg(rows, 512),
+               arg(rows, 1), arg(rows, 1))
+    mem = scatter_planes.lower(*planes, *updates).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cap * 512 * 4
+    assert mem.temp_size_in_bytes < cap * 512 * 4
